@@ -52,6 +52,7 @@ from repro.core import Schedule, compile_bundled, dist  # noqa: E402
 from repro.core.api import bind_cache_clear, compile_cache_clear  # noqa: E402
 from repro.core.codegen import distributed as distmod  # noqa: E402
 from repro.graph import preferential_attachment  # noqa: E402
+from repro.xla_cache import use_persistent_cache  # noqa: E402
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_analysis.json")
 P = 8
@@ -154,4 +155,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_persistent_cache()
     main()

@@ -30,6 +30,7 @@ from repro.autotune import autotune, default_params, schedule_to_dict
 from repro.core import Schedule, compile_bundled, get_context
 from repro.graph import preferential_attachment
 from repro.graph.generators import road
+from repro.xla_cache import use_persistent_cache
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_autotune.json")
 
@@ -108,4 +109,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_persistent_cache()
     main()

@@ -30,6 +30,7 @@ from common import timeit as _timeit_us  # noqa: E402  (shared methodology)
 
 from repro.core import Schedule, compile_bundled, runtime as rt
 from repro.graph import preferential_attachment
+from repro.xla_cache import use_persistent_cache
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_batch.json")
 
@@ -117,4 +118,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_persistent_cache()
     main()

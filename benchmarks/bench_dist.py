@@ -43,6 +43,7 @@ from repro.core import Schedule, compile_bundled, dist  # noqa: E402
 from repro.core.runtime_dist import compact_cap  # noqa: E402
 from repro.graph import preferential_attachment  # noqa: E402
 from repro.graph.algorithms_ref import bfs_levels_ref  # noqa: E402
+from repro.xla_cache import use_persistent_cache  # noqa: E402
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_dist.json")
 P = 8
@@ -143,7 +144,7 @@ def _bfs_runner(g, mesh, policy, frac):
     from jax.sharding import PartitionSpec as PS
 
     from repro.core import runtime_dist as rtd
-    gd = rtd.prepare_graph_1d(g, P)
+    gd = rtd.prepare_graph_1d(g, mesh)
     n_pad = int(gd["own_ids"].size)
     specs = rtd.partition_specs(gd, mesh)
 
@@ -155,9 +156,10 @@ def _bfs_runner(g, mesh, policy, frac):
             frontier=policy, gather_frac=frac,
             direction="auto", threshold_frac=1.0 / 16.0)
 
-    fn = jax.jit(rtd.shard_map(body, mesh=mesh,
+    fn = jax.jit(jax.shard_map(body, mesh=mesh,
                                in_specs=(specs, PS()),
-                               out_specs=(PS("data"), PS(), PS())))
+                               out_specs=(PS("data"), PS(), PS()),
+                               check_vma=False))
     return lambda root: fn(gd, root)
 
 
@@ -249,4 +251,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_persistent_cache()
     main()

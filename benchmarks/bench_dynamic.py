@@ -51,6 +51,7 @@ from common import timeit as _timeit_us  # noqa: E402
 
 from repro.core import Schedule, compile_bundled  # noqa: E402
 from repro.graph import powerlaw_social  # noqa: E402
+from repro.xla_cache import use_persistent_cache  # noqa: E402
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_dynamic.json")
 INF = np.int64(2**30)
@@ -230,4 +231,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_persistent_cache()
     main()

@@ -39,13 +39,15 @@ def run(graphs=None):
     base = {}
     for nd in (1, 2, 4, 8):
         env = dict(os.environ)
+        # a CPU host-device study: the child must never claim the parent's chip
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={nd}"
         env.setdefault("PYTHONPATH", "src")
         proc = subprocess.run([sys.executable, "-c", _SCRIPT, str(nd)], env=env,
                               capture_output=True, text=True, timeout=900)
         if proc.returncode != 0:
-            print(f"fig17/ERROR_{nd},,{proc.stderr[-300:]}")
-            continue
+            raise RuntimeError(
+                f"fig17 child ({nd} devices) failed: {proc.stderr[-300:]}")
         res = json.loads([l for l in proc.stdout.splitlines()
                           if l.startswith("RESULTS:")][0][len("RESULTS:"):])
         for alg, us in res.items():

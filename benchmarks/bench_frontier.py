@@ -26,6 +26,7 @@ from repro.core import runtime as rt
 from repro.graph import preferential_attachment, road
 from repro.graph.csr import INF_I32
 from repro.kernels.ell_spmv import ops as kops
+from repro.xla_cache import use_persistent_cache
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_frontier.json")
 
@@ -196,4 +197,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_persistent_cache()
     main()

@@ -34,6 +34,7 @@ import numpy as np
 from repro.core import get_context, runtime as rt
 from repro.graph import preferential_attachment
 from repro.graph.algorithms_ref import ppr_matrix_ref
+from repro.xla_cache import use_persistent_cache
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_ppr.json")
 DELTA, BETA, MAX_ITER = 0.85, 1e-4, 100
@@ -143,4 +144,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_persistent_cache()
     main()

@@ -45,6 +45,7 @@ from common import weighted_grid  # noqa: E402
 from repro.autotune import autotune  # noqa: E402
 from repro.core import Schedule, compile_bundled  # noqa: E402
 from repro.core.context import get_context  # noqa: E402
+from repro.xla_cache import use_persistent_cache  # noqa: E402
 
 OUT_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_priority.json")
 INF = np.int64(2**30)
@@ -215,4 +216,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_persistent_cache()
     main()

@@ -28,7 +28,7 @@ def timeit(fn, reps=3):
 
 out = {}
 mesh = dist.make_mesh_1d(8)
-mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+mesh2 = dist.make_mesh((4, 2), ("data", "model"))
 graphs = load_suite(["TW", "PK", "US", "RM", "UR"])
 for name, g in graphs.items():
     p = compile_bundled("sssp", backend="distributed")
@@ -45,13 +45,14 @@ print("RESULTS:" + json.dumps(out))
 
 def run(graphs=None):
     env = dict(os.environ)
+    # a CPU host-device study: the child must never claim the parent's chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env.setdefault("PYTHONPATH", "src")
     proc = subprocess.run([sys.executable, "-c", _SCRIPT], env=env,
                           capture_output=True, text=True, timeout=1800)
     if proc.returncode != 0:
-        print(f"table5/ERROR,, {proc.stderr[-500:]}")
-        return
+        raise RuntimeError(f"table5 child failed: {proc.stderr[-500:]}")
     res = json.loads([l for l in proc.stdout.splitlines()
                       if l.startswith("RESULTS:")][0][len("RESULTS:"):])
     for key, us in sorted(res.items()):

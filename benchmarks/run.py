@@ -12,6 +12,9 @@ def main() -> None:
                     help="small graph subset (CI-speed)")
     args = ap.parse_args()
 
+    from repro.xla_cache import use_persistent_cache
+    use_persistent_cache()
+
     from .common import header
     from . import (bench_fig17_scaling, bench_table3_openmp,
                    bench_table4_scheduling, bench_table5_mpi,
@@ -23,6 +26,7 @@ def main() -> None:
         graphs = load_suite(["PK", "US", "UR"])
 
     header()
+    failed = []
     tables = {
         "table3": lambda: bench_table3_openmp.run(graphs),
         "table4": lambda: bench_table4_scheduling.run(graphs),
@@ -38,6 +42,9 @@ def main() -> None:
             fn()
         except Exception as e:  # keep the harness going; report the failure
             print(f"{name}/HARNESS_ERROR,,{type(e).__name__}: {e}")
+            failed.append(name)
+    if failed:
+        sys.exit(f"failed tables: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

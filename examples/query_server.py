@@ -34,6 +34,7 @@ from repro.core import compile_bundled
 from repro.graph import preferential_attachment
 from repro.graph.algorithms_ref import bc_ref, sssp_ref
 from repro.serve import GraphService, ServiceConfig
+from repro.xla_cache import use_persistent_cache
 
 
 async def serve(args, svc: GraphService, graphs: dict):
@@ -154,4 +155,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_persistent_cache()
     main()

@@ -6,6 +6,7 @@ import numpy as np
 
 from repro.core import compile_program
 from repro.graph import uniform_random
+from repro.xla_cache import use_persistent_cache
 
 SSSP_SOURCE = """
 // Single-source shortest paths (paper Fig. 3)
@@ -63,4 +64,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_persistent_cache()
     main()

@@ -14,6 +14,7 @@ from repro.models import build
 from repro.serve import ServeEngine
 from repro.train import OptimizerConfig, init_state, make_train_step
 from repro.train.data import DataConfig, batch_at
+from repro.xla_cache import use_persistent_cache
 
 
 def main():
@@ -42,4 +43,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_persistent_cache()
     main()

@@ -16,6 +16,7 @@ from repro.models import build
 from repro.train import (OptimizerConfig, checkpoint as ckpt, init_state,
                          make_train_step)
 from repro.train.data import DataConfig, batch_at
+from repro.xla_cache import use_persistent_cache
 
 
 def main():
@@ -62,4 +63,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_persistent_cache()
     main()

@@ -19,11 +19,12 @@ This module closes the loop:
      is a cache hit — across tuning runs too) and times `prog.bind(g)`
      executions with warm-up, taking the min over repetitions.
   3. **Persistence** — results land in a `TuningRecord` keyed by
-     ``(source digest, backend, graph fingerprint)`` that round-trips
-     through JSON via `TuningStore`, so a server process tunes once and
-     reloads thereafter; a stored record whose digest or fingerprint no
-     longer matches (the program or the graph changed) is rejected and
-     re-tuned rather than silently replayed.
+     ``(source digest, backend, graph fingerprint, device kind)`` that
+     round-trips through JSON via `TuningStore`, so a server process tunes
+     once and reloads thereafter; a stored record whose digest or
+     fingerprint no longer matches (the program or the graph changed), or
+     that was timed on another kind of device, is rejected and re-tuned
+     rather than silently replayed.
 
 Entry point::
 
@@ -51,6 +52,7 @@ import os
 import time
 from typing import Callable, List, Optional, Union
 
+import jax
 import numpy as np
 
 from .core.analysis import ERROR, check_schedule, program_analysis
@@ -58,7 +60,14 @@ from .core.api import CompiledProgram
 from .core.context import get_context
 from .schedule import LANE_MULTIPLE, Schedule
 
-RECORD_VERSION = 1
+RECORD_VERSION = 2   # 2: records are keyed by device kind too
+
+
+def device_kind() -> str:
+    """The kind of device this process computes on, as JAX reports it
+    (e.g. "cpu", "TPU v5 lite") — a schedule tuned on one kind says
+    nothing about another."""
+    return jax.devices()[0].device_kind
 
 # stats thresholds the pruning branches on (see GraphContext.stats())
 _SKEWED_CV = 0.5          # degree CV above this = power-law-like
@@ -308,11 +317,12 @@ def measure_wallclock(bound, params: dict, *, warmup: int = 1,
 class TuningRecord:
     """One finished tuning run, JSON-serializable.
 
-    Keyed by ``(source_digest, backend, graph_fingerprint)``: the digest
-    pins the *algorithm text*, the fingerprint pins the *graph contents*
-    — if either changed since the record was written, replaying the
-    stored schedule would be tuning for a different problem, so lookups
-    reject the record and the caller re-tunes."""
+    Keyed by ``(source_digest, backend, graph_fingerprint, device_kind)``:
+    the digest pins the *algorithm text*, the fingerprint pins the *graph
+    contents*, the device kind the *hardware* the trials were timed on — if
+    any of them differs, replaying the stored schedule would be tuning for
+    a different problem, so lookups reject the record and the caller
+    re-tunes."""
 
     source_digest: str
     backend: str
@@ -330,10 +340,13 @@ class TuningRecord:
     # whose best schedule seeded trial #0 ("" = unseeded run). Each trial
     # dict also carries "source": "seeded" | "search".
     seeded_from: str = ""
+    # the device the trials ran on (default: this process's device)
+    device_kind: str = dataclasses.field(default_factory=device_kind)
     version: int = RECORD_VERSION
 
     def key(self) -> tuple:
-        return (self.source_digest, self.backend, self.graph_fingerprint)
+        return (self.source_digest, self.backend, self.graph_fingerprint,
+                self.device_kind)
 
     def best_schedule(self) -> Schedule:
         return schedule_from_dict(self.schedule)
@@ -432,13 +445,17 @@ class TuningStore:
 
     def lookup(self, digest: str, backend: str,
                fingerprint: str) -> Optional[TuningRecord]:
-        rec = self._records.get((digest, backend, fingerprint))
+        """The record tuned for this key on this process's kind of device,
+        or None."""
+        kind = device_kind()
+        rec = self._records.get((digest, backend, fingerprint, kind))
         if rec is None:
             return None
         # strict validation: a record is only trusted if its own fields
         # restate the key it is filed under and its version is current
         if (rec.source_digest != digest or rec.backend != backend
                 or rec.graph_fingerprint != fingerprint
+                or rec.device_kind != kind
                 or rec.version != RECORD_VERSION):
             return None
         return rec
@@ -484,14 +501,15 @@ def stats_distance(a: dict, b: dict) -> float:
 
 def nearest_record(store: TuningStore, digest: str, backend: str,
                    stats: dict) -> Optional[TuningRecord]:
-    """The store record for the same (program, backend) whose graph stats
-    are nearest to `stats`, or None when the store has nothing comparable.
-    Deterministic: ties break toward the smaller fingerprint (store order
-    is sorted)."""
+    """The store record for the same (program, backend, this process's
+    device kind) whose graph stats are nearest to `stats`, or None when the
+    store has nothing comparable. Deterministic: ties break toward the
+    smaller fingerprint (store order is sorted)."""
+    kind = device_kind()
     best, best_d = None, float("inf")
     for rec in store.records():
         if rec.source_digest != digest or rec.backend != backend \
-                or not rec.graph_stats:
+                or rec.device_kind != kind or not rec.graph_stats:
             continue
         d = stats_distance(stats, rec.graph_stats)
         if d < best_d:
@@ -539,7 +557,8 @@ def autotune(prog: CompiledProgram, g, *, budget: int = 16, seed: int = 0,
     * `measure(bound, params) -> seconds` replaces the wall-clock timer
       (tests inject a deterministic cost model here).
     * `store` (a `TuningStore` or a path) persists the result; a valid
-      stored record for (source digest, backend, graph fingerprint) skips
+      stored record for (source digest, backend, graph fingerprint, this
+      process's device kind) skips
       measurement entirely, and a record whose digest or fingerprint no
       longer matches is ignored and re-tuned. On a miss, records for the
       same (program, backend) on OTHER graphs act as a cost model: the
@@ -655,7 +674,8 @@ def autotune(prog: CompiledProgram, g, *, budget: int = 16, seed: int = 0,
         schedule=schedule_to_dict(best),
         best_ms=trials[best_i]["ms"], default_ms=trials[base_i]["ms"],
         trials=trials, budget=budget, seed=seed, graph_stats=dict(stats),
-        pruned_candidates=pruned, seeded_from=seeded_from)
+        pruned_candidates=pruned, seeded_from=seeded_from,
+        device_kind=device_kind())
     if store is not None:
         store.put(record)
         store.save()

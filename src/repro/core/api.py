@@ -129,10 +129,10 @@ class BoundProgram:
         self.graph = graph
         ctx = get_context(graph)
         if program.backend == "distributed":
-            from . import dist, runtime_dist as rtd
+            from . import dist
             self.mesh = mesh if mesh is not None else dist.make_mesh_1d()
             meta = program.dist_meta or {}
-            self._gd = ctx.dist_arrays(self.mesh.shape[rtd.AXIS],
+            self._gd = ctx.dist_arrays(self.mesh,
                                        ell=meta.get("needs_ell", False))
         else:
             if mesh is not None:
@@ -271,11 +271,17 @@ def compile_program(source: str, backend: str = "local",
     fixed points) and illegal schedule combinations raise
     `DiagnosticError` with stable SPxxx codes; `strict=True` promotes
     warnings to errors.  Surviving warnings ride on the returned program's
-    `.diagnostics`."""
+    `.diagnostics`.
+
+    On a TPU, `backend="pallas"` raises `NotImplementedError`: Mosaic
+    refuses its kernel (`repro.kernels.ell_spmv.ops.TPU_REFUSAL`)."""
     if backend not in _BACKENDS:
         raise entry_error(
             "SP301",
             f"unknown backend {backend!r}; backends: {', '.join(_BACKENDS)}")
+    if backend == "pallas" and jax.default_backend() == "tpu":
+        from ..kernels.ell_spmv.ops import TPU_REFUSAL
+        raise NotImplementedError(TPU_REFUSAL)
     sched = resolve_schedule(schedule, batch_sources=batch_sources)
 
     # --- static analysis gate (runs before the cache: rejection must not

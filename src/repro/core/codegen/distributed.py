@@ -161,7 +161,7 @@ class DistCodegen(LocalCodegen):
                 em.w(f"{k} = {gd}['{k}']")
             em.w("n_true = n_true_rep")
             em.w("B = own_ids.shape[0]")
-            em.w("P = rtd.axis_size('data')")
+            em.w("P = jax.lax.axis_size('data')")
             em.w("N_PAD = B * P")
             em.w("_vids = own_ids")
             em.w("_vids_full = jnp.arange(N_PAD, dtype=jnp.int32)")

@@ -11,7 +11,7 @@ module registry:
 
     ctx = get_context(g)                 # registered on first touch
     ell = ctx.sliced_ell(schedule)       # built once per (layout, reverse)
-    gd  = ctx.dist_arrays(num_shards)    # built once per partitioning
+    gd  = ctx.dist_arrays(mesh)          # built once per partitioning
 
 Entries hold a WEAK reference to the graph: `id(g)` alone is unsafe (ids
 are reused after GC, so a dead graph could alias a new one's views) and a
@@ -151,12 +151,13 @@ class GraphContext:
         return self.view(("padded", int(multiple)),
                          lambda g: pad_nodes(g, multiple))
 
-    def dist_arrays(self, num_shards: int, *, ell: bool = False) -> dict:
-        """1-D block-partitioned device arrays for the distributed backend."""
+    def dist_arrays(self, mesh, *, ell: bool = False) -> dict:
+        """1-D block-partitioned arrays for the distributed backend, placed
+        on `mesh` (one view per mesh; key[1] is its shard count)."""
         from . import runtime_dist as rtd
-        key = ("dist_1d", int(num_shards), bool(ell))
+        key = ("dist_1d", int(mesh.shape[rtd.AXIS]), bool(ell), mesh)
         return self.view(key, lambda g: rtd.prepare_graph_1d(
-            g, num_shards, ell=ell))
+            g, mesh, ell=ell))
 
     def fingerprint(self) -> str:
         """Stable content digest of the graph (structure + weights).
@@ -344,13 +345,11 @@ def prepare(g: CSRGraph, schedule: Optional[Schedule] = None, *,
     if backend == "pallas":
         ctx.sliced_ell(sched, reverse=True)
     elif backend == "distributed":
-        from . import runtime_dist as rtd
         if mesh is None:
             from .dist import make_mesh_1d
             mesh = make_mesh_1d()
         meta = (getattr(program, "dist_meta", None) or {})
-        ctx.dist_arrays(mesh.shape[rtd.AXIS],
-                        ell=meta.get("needs_ell", False))
+        ctx.dist_arrays(mesh, ell=meta.get("needs_ell", False))
     elif backend != "local":
         raise ValueError(
             f"unknown backend {backend!r}; expected 'local', 'pallas', or "

@@ -9,23 +9,31 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from ..graph.csr import CSRGraph
 from . import runtime_dist as rtd
 
 
+def make_mesh(shape: tuple, axes: tuple, devices=None):
+    """A mesh with Auto axis types: the graph runners trim and reshape their
+    sharded outputs outside `shard_map`, which Explicit axes (the default of
+    `jax.make_mesh`) refuse."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_mesh_1d(num_devices: int | None = None):
     devs = jax.devices()
     n = num_devices or len(devs)
-    return jax.make_mesh((n,), (rtd.AXIS,), devices=devs[:n])
+    return make_mesh((n,), (rtd.AXIS,), devices=devs[:n])
 
 
 def prepare(g: CSRGraph, mesh, *, ell: bool = False) -> dict:
     """Partitioned device arrays for `g`, memoized in the graph's shared
     `GraphContext` — repeated runs against one graph partition it once."""
     from .context import get_context
-    return get_context(g).dist_arrays(mesh.shape[rtd.AXIS], ell=ell)
+    return get_context(g).dist_arrays(mesh, ell=ell)
 
 
 def run(prog, g: CSRGraph, mesh, **params):
@@ -73,10 +81,10 @@ def run_pod_parallel(prog, g: CSRGraph, mesh, source_set, **params):
 
     out_specs = {v: P(rtd.AXIS) for v in meta.get("out_props", [])}
     out_specs.update({v: P() for v in meta.get("out_scalars", [])})
-    fn = jax.jit(rtd.shard_map(
+    fn = jax.jit(jax.shard_map(
         pod_body, mesh=mesh,
         in_specs=(in_specs, P("pod")) + tuple(P() for _ in other),
-        out_specs=out_specs))
+        out_specs=out_specs, check_vma=False))
     out = fn(gd, jnp.asarray(srcs), *other)
     return {k: (v[: g.num_nodes] if k in meta.get("out_props", ()) else v)
             for k, v in out.items()}
@@ -116,10 +124,10 @@ def _runner(prog, gd: dict, mesh, names: tuple, meta: dict):
         out_specs = {v: P(rtd.AXIS) for v in meta.get("out_props", [])}
         out_specs.update({v: P() for v in meta.get("out_scalars", [])})
         body = prog.raw_fn
-        fn = cache[key] = jax.jit(rtd.shard_map(
+        fn = cache[key] = jax.jit(jax.shard_map(
             lambda gd_, *vs: body(gd_, **dict(zip(names, vs))),
             mesh=mesh,
             in_specs=(in_specs,) + tuple(P() for _ in names),
-            out_specs=out_specs,
+            out_specs=out_specs, check_vma=False,
         ))
     return fn

@@ -22,12 +22,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..graph.csr import CSRGraph, INF_I32
 from ..graph.partition import partition_2d
 from . import runtime as rt
-from .runtime_dist import shard_map as _shard_map
 
 DATA, MODEL = "data", "model"
 
@@ -47,11 +46,10 @@ def prepare_graph_2d(g: CSRGraph, rows: int, cols: int) -> dict:
     }
 
 
-def specs_2d(mesh):
-    return {
-        "src_local": P(DATA, MODEL, None), "dst_local": P(DATA, MODEL, None),
-        "weight": P(DATA, MODEL, None), "valid": P(DATA, MODEL, None),
-    }
+def _place(mesh, spec, x):
+    """Put a host array on `mesh` with its final sharding, so the jitted
+    step never reshards it from the default device."""
+    return jax.device_put(x, NamedSharding(mesh, spec))
 
 
 def _own_global_ids(piece, c):
@@ -107,11 +105,13 @@ def sssp_2d(g: CSRGraph, mesh, src: int = 0):
         dist, _ = jax.lax.while_loop(cond, step, (dist, jnp.bool_(False)))
         return dist
 
-    fn = jax.jit(_shard_map(
+    tile = P(DATA, MODEL, None)
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(DATA, MODEL, None),) * 4 + (P(),),
-        out_specs=P((DATA, MODEL))))
-    out = fn(gd["src_local"], gd["dst_local"], gd["weight"], gd["valid"],
+        in_specs=(tile,) * 4 + (P(),),
+        out_specs=P((DATA, MODEL)), check_vma=False))
+    out = fn(*(_place(mesh, tile, gd[k])
+               for k in ("src_local", "dst_local", "weight", "valid")),
              jnp.int32(src))
     return out[: g.num_nodes]
 
@@ -165,9 +165,11 @@ def pagerank_2d(g: CSRGraph, mesh, delta: float = 0.85, beta: float = 1e-4,
             cond, step, (pr, jnp.float32(0), jnp.int32(0), jnp.bool_(True)))
         return pr
 
-    fn = jax.jit(_shard_map(
+    tile = P(DATA, MODEL, None)
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(DATA, MODEL, None),) * 3 + (P(MODEL, None),),
-        out_specs=P((DATA, MODEL))))
-    out = fn(gd.src_local, gd.dst_local, gd.valid, jnp.asarray(deg_xj))
+        in_specs=(tile,) * 3 + (P(MODEL, None),),
+        out_specs=P((DATA, MODEL)), check_vma=False))
+    out = fn(_place(mesh, tile, gd.src_local), _place(mesh, tile, gd.dst_local),
+             _place(mesh, tile, gd.valid), _place(mesh, P(MODEL, None), deg_xj))
     return out[: g.num_nodes]

@@ -24,6 +24,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..graph.csr import CSRGraph
 from ..graph.partition import block_partition_1d
@@ -32,39 +33,18 @@ from . import runtime as rt
 AXIS = "data"
 
 
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-compat shard_map: `jax.shard_map(..., check_vma=False)` on
-    new jax, `jax.experimental.shard_map.shard_map(..., check_rep=False)`
-    on 0.4.x — same semantics (replication checking off; the generated
-    bodies use collectives explicitly)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def axis_size(name: str) -> int:
-    """Static mesh-axis size from inside a shard_map body. `psum(1, axis)`
-    constant-folds to a Python int on every jax line; `lax.axis_size` only
-    exists on newer ones."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
 # --------------------------------------------------------------------------
 # Graph preparation (host side)
 # --------------------------------------------------------------------------
 
-def prepare_graph_1d(g: CSRGraph, num_devices: int, *, ell: bool = False) -> dict:
-    """Device-stacked arrays for the 1-D partitioned backend.
+def prepare_graph_1d(g: CSRGraph, mesh, *, ell: bool = False) -> dict:
+    """Partitioned arrays for the 1-D backend, placed on `mesh` once.
 
     Keys with leading [P] shard over the mesh 'data' axis; `*_rep` keys are
     replicated static graph structure (degree tables, the sorted edge key
-    for is_an_edge)."""
-    p = num_devices
+    for is_an_edge). Each array is put with its `NamedSharding` here, so a
+    jitted runner never reshards the graph from one device per call."""
+    p = mesh.shape[AXIS]
     out = block_partition_1d(g, p)                      # out-edges by src block
     # in-edges partitioned by dst block: build from the reverse CSR
     rev = CSRGraph(
@@ -86,37 +66,35 @@ def prepare_graph_1d(g: CSRGraph, num_devices: int, *, ell: bool = False) -> dic
     deg_in[: g.num_nodes] = np.asarray(g.in_degree)
 
     gd = {
-        "esrc": jnp.asarray(out.src), "edst": jnp.asarray(out.dst),
-        "ew": jnp.asarray(out.weight), "evalid": jnp.asarray(out.valid),
+        "esrc": out.src, "edst": out.dst, "ew": out.weight, "evalid": out.valid,
         # local slot of the source vertex; padding edges clipped to 0 and
         # neutralized by the valid mask
-        "esrc_local": jnp.asarray(np.clip(
-            out.src - (np.arange(p) * block)[:, None], 0, block - 1).astype(np.int32)),
+        "esrc_local": np.clip(
+            out.src - (np.arange(p) * block)[:, None], 0, block - 1).astype(np.int32),
         # in-edge arrays: src field of `inn` is the OWNED dst, dst field is the in-neighbor
-        "idst": jnp.asarray(inn.src), "isrc": jnp.asarray(inn.dst),
-        "iw": jnp.asarray(inn.weight), "ivalid": jnp.asarray(inn.valid),
-        "idst_local": jnp.asarray(np.clip(
-            inn.src - (np.arange(p) * block)[:, None], 0, block - 1).astype(np.int32)),
-        "own_ids": jnp.asarray(own_ids),
-        "out_degree_rep": jnp.asarray(deg_out),
-        "in_degree_rep": jnp.asarray(deg_in),
-        "n_true_rep": jnp.asarray(g.num_nodes, jnp.int32),
+        "idst": inn.src, "isrc": inn.dst, "iw": inn.weight, "ivalid": inn.valid,
+        "idst_local": np.clip(
+            inn.src - (np.arange(p) * block)[:, None], 0, block - 1).astype(np.int32),
+        "own_ids": own_ids,
+        "out_degree_rep": deg_out,
+        "in_degree_rep": deg_in,
+        "n_true_rep": np.asarray(g.num_nodes, np.int32),
+        "edge_key_rep": np.asarray(g.edge_key),   # cached, built once in from_edges
     }
-    gd["edge_key_rep"] = g.edge_key   # cached, built once in from_edges
     if ell:
         from ..graph.csr import to_ell
         e = to_ell(g)
         cols = np.asarray(e.cols)
         cols_pad = np.full((n_pad, e.max_deg), n_pad, np.int32)
         cols_pad[: g.num_nodes] = np.where(cols == g.num_nodes, n_pad, cols)
-        gd["ell_cols"] = jnp.asarray(
-            cols_pad.reshape(p, block, e.max_deg))
-    return gd
+        gd["ell_cols"] = cols_pad.reshape(p, block, e.max_deg)
+    specs = partition_specs(gd, mesh)
+    return {k: jax.device_put(v, NamedSharding(mesh, specs[k]))
+            for k, v in gd.items()}
 
 
 def partition_specs(gd: dict, mesh):
     """PartitionSpec per gd key: stacked arrays shard on 'data', *_rep replicate."""
-    from jax.sharding import PartitionSpec as P
     specs = {}
     for k, v in gd.items():
         if k.endswith("_rep"):
@@ -182,7 +160,7 @@ def exchange(full_prev, blk, own_ids, gather_frac: float = 0.25, *,
     so poison seeded into padding stays untouched (tested)."""
     n_pad = full_prev.shape[0]
     cap = compact_cap(blk.shape[0], gather_frac)
-    p = axis_size(AXIS)
+    p = jax.lax.axis_size(AXIS)
     chg = blk != full_prev[own_ids]
     if within is not None:
         chg = chg & within

@@ -13,9 +13,13 @@ Two layouts coexist:
     tiles + a COO hub fallback — the frontier-aware engine's layout.
     `relax_minplus` / `gather_plustimes` dispatch on the first argument.
 
-On non-TPU hosts the sliced ops run an equivalent pure-jnp path instead of
+Off the TPU the sliced ops run an equivalent pure-jnp path instead of
 interpret-mode Pallas: identical math, without the interpreter overhead
-(the kernels proper are still exercised by tests/test_kernels.py).
+(the kernels proper are still exercised by tests/test_kernels.py). On a
+TPU they call the kernel, which Mosaic refuses (`TPU_REFUSAL`); so
+`compile_program(..., backend="pallas")` refuses there up front rather
+than run the jnp path under the pallas name. Both decisions are made per
+call, from `jax.default_backend()`, never at import.
 """
 from __future__ import annotations
 
@@ -29,8 +33,26 @@ from ...graph.csr import (CSRGraph, INF_I32, SlicedEllGraph, to_ell,
                           to_sliced_ell)
 from .kernel import _best_block, ell_spmv
 
-_INTERPRET = jax.default_backend() != "tpu"
-_USE_KERNEL = not _INTERPRET   # pure-jnp fallback off-TPU (same semantics)
+# Mosaic's answers when `ell_spmv` is compiled for a TPU v5e (JAX 0.9.0);
+# tests/test_chip_compile.py checks they are still what it says.
+MOSAIC_ERRORS = ("Only 2D gather is supported",                 # x: [N+1]
+                 "Shape mismatch in input, indices and output")  # x: [N+1, B]
+TPU_REFUSAL = (
+    "the pallas backend does not run on a TPU: Mosaic refuses the ell_spmv "
+    f"kernel's gather of x ({MOSAIC_ERRORS[0]!r} for an [N+1] x, "
+    f"{MOSAIC_ERRORS[1]!r} for an [N+1, B] x), and the kernel keeps all of x "
+    "in VMEM, which a 32-lane x of 2^21 nodes (268 MB) outgrows; compile "
+    "with backend='local'")
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def _use_kernel() -> bool:
+    """The bucket ops call the Pallas kernel on a TPU only; elsewhere they
+    run the same math in jnp."""
+    return not _interpret()
 
 INF = jnp.int32(INF_I32)
 
@@ -87,7 +109,7 @@ def _relax_dense(cols, wts, dist, *, block_rows: int = 256):
     # and 0 keeps INF(pad weight) + x from overflowing int32.
     x = jnp.zeros((n_pad + 1,), dist.dtype).at[:n].set(dist)
     y = ell_spmv(cols, wts, x, semiring="minplus",
-                 block_rows=block_rows, interpret=_INTERPRET)
+                 block_rows=block_rows, interpret=_interpret())
     return jnp.minimum(dist, y[:n])
 
 
@@ -99,7 +121,7 @@ def _gather_dense(cols, contrib, *, block_rows: int = 256):
     ones = jnp.where(cols == n_pad, 0.0, 1.0).astype(contrib.dtype)
     x = jnp.zeros((n_pad + 1,), contrib.dtype).at[:n].set(contrib)
     y = ell_spmv(cols, ones, x, semiring="plustimes",
-                 block_rows=block_rows, interpret=_INTERPRET)
+                 block_rows=block_rows, interpret=_interpret())
     return y
 
 
@@ -123,21 +145,21 @@ def _bucket_caps(ell: SlicedEllGraph, block_rows):
 
 def _bucket_minplus(cols, wts, x, cap: int = 256):
     """x: [M] (SpMV) or [M, B] (SpMM, lanes = source batch)."""
-    if _USE_KERNEL:
+    if _use_kernel():
         return ell_spmv(cols, wts, x, semiring="minplus",
                         block_rows=_best_block(cols.shape[0], cap),
-                        interpret=_INTERPRET)
+                        interpret=_interpret())
     if x.ndim == 2:
         wts = wts[..., None]
     return jnp.min(jnp.take(x, cols, axis=0) + wts, axis=1)
 
 
 def _bucket_plustimes(cols, x, cap: int = 256):
-    if _USE_KERNEL:
+    if _use_kernel():
         ones = jnp.ones(cols.shape, x.dtype)   # pads hit the 0 sentinel
         return ell_spmv(cols, ones, x, semiring="plustimes",
                         block_rows=_best_block(cols.shape[0], cap),
-                        interpret=_INTERPRET)
+                        interpret=_interpret())
     return jnp.sum(jnp.take(x, cols, axis=0), axis=1)
 
 

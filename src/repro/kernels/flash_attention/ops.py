@@ -9,8 +9,6 @@ import jax.numpy as jnp
 from .kernel import flash_attention
 from .ref import attention_ref
 
-_INTERPRET = jax.default_backend() != "tpu"
-
 
 @partial(jax.jit, static_argnames=("causal", "use_kernel"))
 def gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -25,7 +23,8 @@ def gqa_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kf = k.reshape(b * hq, -1, d)
     vf = v.reshape(b * hq, -1, d)
     if use_kernel and sq >= 8:
-        o = flash_attention(qf, kf, vf, causal=causal, interpret=_INTERPRET)
+        o = flash_attention(qf, kf, vf, causal=causal,
+                            interpret=jax.default_backend() != "tpu")
     else:
         o = attention_ref(qf, kf, vf, causal=causal)
     return o.reshape(b, hq, sq, d)

@@ -8,7 +8,8 @@ array instead of independent threads, so we feed it dense tiles:
 
   grid (I, J, K) over [N/B]³ tiles; A_ik @ A_kj accumulates into a VMEM
   scratch; on the last K step the tile of C is masked by A_ij and reduced
-  into a per-(I,J) partial count.
+  into a per-(I,J) partial count, written to element [0, 0] of that
+  (I, J)'s own (8, 128) output tile — the smallest block Mosaic tiles.
 
 Dense N² is the price of MXU regularity — viable for the per-device vertex
 blocks the distributed layer produces (B_block ≤ a few thousand), which is
@@ -24,6 +25,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+_OUT_TILE = (8, 128)
+
+
 def _tc_body(a_ik_ref, a_kj_ref, a_ij_ref, out_ref, acc_ref, *, n_k: int):
     k = pl.program_id(2)
 
@@ -36,7 +40,10 @@ def _tc_body(a_ik_ref, a_kj_ref, a_ij_ref, out_ref, acc_ref, *, n_k: int):
 
     @pl.when(k == n_k - 1)
     def _final():
-        out_ref[0, 0] = jnp.sum(acc_ref[...] * a_ij_ref[...])
+        count = jnp.sum(acc_ref[...] * a_ij_ref[...])
+        first = ((jax.lax.broadcasted_iota(jnp.int32, _OUT_TILE, 0) == 0)
+                 & (jax.lax.broadcasted_iota(jnp.int32, _OUT_TILE, 1) == 0))
+        out_ref[...] = jnp.where(first, count, 0.0)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -55,8 +62,9 @@ def tc_matmul(lower: jax.Array, *, block: int = 128,
             pl.BlockSpec((block, block), lambda i, j, k: (k, j)),   # A_kj
             pl.BlockSpec((block, block), lambda i, j, k: (i, j)),   # mask A_ij
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((nb, nb), jnp.float32),
+        out_specs=pl.BlockSpec(_OUT_TILE, lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((nb * _OUT_TILE[0], nb * _OUT_TILE[1]),
+                                       jnp.float32),
         scratch_shapes=[pltpu.VMEM((block, block), jnp.float32)],
         interpret=interpret,
     )(lower, lower, lower)

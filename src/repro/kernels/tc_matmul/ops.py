@@ -19,9 +19,6 @@ import numpy as np
 from ...graph.csr import CSRGraph
 from .kernel import tc_matmul
 
-_INTERPRET = jax.default_backend() != "tpu"
-
-
 def prepare_lower(g: CSRGraph, block: int = 128) -> jax.Array:
     """Dense strict-lower adjacency of the undirected closure, block-padded."""
     n = g.num_nodes
@@ -39,4 +36,5 @@ def prepare_lower(g: CSRGraph, block: int = 128) -> jax.Array:
 @partial(jax.jit, static_argnames=("block",))
 def count_triangles_dense(lower: jax.Array, *, block: int = 128) -> jax.Array:
     block = min(block, lower.shape[0])
-    return tc_matmul(lower, block=block, interpret=_INTERPRET).astype(jnp.int32)
+    return tc_matmul(lower, block=block,
+                     interpret=jax.default_backend() != "tpu").astype(jnp.int32)

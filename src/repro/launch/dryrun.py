@@ -140,7 +140,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str = OUT_DIR):
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
     mem = compiled.memory_analysis()
-    cost = hlo_cost.xla_cost_dict(compiled)
+    cost = compiled.cost_analysis()
     # loop-aware per-device census from the optimized HLO (hlo_cost.py):
     # cost_analysis() counts while bodies once and is kept as a cross-check.
     census = hlo_cost.analyze(compiled.as_text())

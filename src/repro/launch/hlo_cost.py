@@ -21,14 +21,6 @@ import re
 from dataclasses import dataclass, field
 
 
-def xla_cost_dict(compiled) -> dict:
-    """`compiled.cost_analysis()` normalized across jax versions: 0.4.x
-    returns a one-element list of dicts, newer jax returns the dict."""
-    c = compiled.cost_analysis()
-    if isinstance(c, (list, tuple)):
-        c = c[0] if c else {}
-    return c
-
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
     "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,

@@ -32,7 +32,8 @@ from .mesh import effective_batch_axes
 def make_mesh(spec: str):
     dims = tuple(int(x) for x in spec.split(","))
     names = ("pod", "data", "model")[-len(dims):]
-    return jax.make_mesh(dims, names)
+    return jax.make_mesh(dims, names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(dims))
 
 
 def run(arch: str, mesh_spec: str, steps: int, *, smoke: bool = True,

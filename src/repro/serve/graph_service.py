@@ -420,9 +420,9 @@ class GraphService:
     def _warm_schedule(self, kind: QueryKind, ctx,
                        handle) -> Optional[Schedule]:
         """TuningStore warm-reload: a persisted record for (program digest,
-        backend, graph fingerprint) supplies the serving schedule, so a
-        registered graph's first query hits the tuned path without a
-        measurement sweep."""
+        backend, graph fingerprint, this process's device kind) supplies
+        the serving schedule, so a registered graph's first query hits the
+        tuned path without a measurement sweep."""
         if self.tune_store is None or not kind.program:
             return None
         digest = source_digest(load_program_source(kind.program))

@@ -1,0 +1,119 @@
+"""Compiles of the main path for a described TPU v5e (no chip needed).
+
+The TPU compiler is installed with JAX and compiles for a chip that is
+described, not attached. These tests compile the per-iteration kernels of
+the local backend at the shapes of an RMAT scale-21 graph (the size
+`chip_smoke.py` runs) and check they fit one v5e's 16 GB, and they pin
+what Mosaic answers for the Pallas ELL kernel. Nothing runs, so they say
+nothing about results or times.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, so under several pytest
+workers only the worker given this file may try, and every worker must
+collect the same tests.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import compile_bundled, runtime as rt
+from repro.graph.csr import CSRGraph
+from repro.kernels.ell_spmv.kernel import ell_spmv
+from repro.kernels.ell_spmv.ops import MOSAIC_ERRORS
+from repro.kernels.tc_matmul.kernel import tc_matmul
+
+HBM_BYTES = 16 * 10 ** 9          # one TPU v5e
+N = 2 ** 21                       # rmat(21, edge_factor=16)
+E = 32_417_925                    # its edges after dedup (seed 0)
+LANES = 32                        # Schedule.batch_sources: one serve sweep
+PR_LANES = 8                      # the smoke's concurrent ppr users
+ELL_WIDTH, ELL_ROWS, ELL_BLOCK = 128, 4096, 256
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    mp = pytest.MonkeyPatch()
+    mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        mp.undo()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    mp.undo()
+
+
+def _shape(sharding, shape, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _graph(sharding) -> CSRGraph:
+    v = lambda n: _shape(sharding, (n,))
+    return CSRGraph(
+        indptr=v(N + 1), indices=v(E), weights=v(E), edge_src=v(E),
+        rev_indptr=v(N + 1), rev_indices=v(E), rev_weights=v(E),
+        rev_edge_dst=v(E), out_degree=v(N), in_degree=v(N), edge_key=v(E),
+        num_nodes=N, num_edges=E, max_out_degree=1 << 17,
+        max_in_degree=1 << 17)
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes)
+
+
+def test_batched_relax_fits_one_chip(one_chip):
+    """One superstep of a 32-lane SSSP sweep (what a coalesced serve sweep
+    runs), push/pull switch included."""
+    step = jax.jit(lambda g, d, f: rt.relax_minplus_hybrid_batch(
+        g, d, f, threshold_frac=1 / 16))
+    compiled = step.lower(_graph(one_chip), _shape(one_chip, (LANES, N)),
+                          _shape(one_chip, (LANES, N), jnp.bool_)).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_pagerank_reverse_edge_sum_fits_one_chip(one_chip):
+    """PR's pull: a lane-batched segment sum over the reverse edges."""
+    pull = jax.jit(lambda vals, g: rt.segment_sum_batch(
+        vals, g.rev_edge_dst, N))
+    compiled = pull.lower(_shape(one_chip, (PR_LANES, E), jnp.float32),
+                          _graph(one_chip)).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("lanes,error", [(None, MOSAIC_ERRORS[0]),
+                                         (LANES, MOSAIC_ERRORS[1])])
+def test_mosaic_refuses_ell_spmv(one_chip, lanes, error):
+    """The refusal `compile_program(backend="pallas")` quotes on a TPU is
+    still what Mosaic says; if this starts to compile, the pallas backend
+    can be let onto the chip."""
+    x_shape = (N + 1,) if lanes is None else (N + 1, lanes)
+    cols = _shape(one_chip, (ELL_ROWS, ELL_WIDTH))
+    spmv = jax.jit(lambda c, v, x: ell_spmv(
+        c, v, x, semiring="minplus", block_rows=ELL_BLOCK, interpret=False))
+    with pytest.raises(Exception, match=error):
+        spmv.lower(cols, cols, _shape(one_chip, x_shape)).compile()
+
+
+def test_tc_matmul_compiles(one_chip):
+    lower = _shape(one_chip, (1024, 1024), jnp.float32)
+    compiled = jax.jit(lambda a: tc_matmul(a, block=128, interpret=False)
+                       ).lower(lower).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pallas_backend_refuses_on_tpu(monkeypatch):
+    """On a TPU the pallas backend raises instead of running the jnp
+    fallback under its name; elsewhere it compiles."""
+    assert compile_bundled("sssp", backend="pallas").backend == "pallas"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="Only 2D gather"):
+        compile_bundled("sssp", backend="pallas")
+    assert compile_bundled("sssp", backend="local").backend == "local"
+

@@ -7,10 +7,10 @@ graph's diameter. This module sweeps those axes on the 8 forced host
 devices (see conftest.py); test_distributed.py keeps the one-shape smoke
 next to the 1-D agreement tests.
 """
-import jax
 import numpy as np
 import pytest
 
+from repro.core import dist
 from repro.core.dist2d import pagerank_2d, sssp_2d
 from repro.graph import road, uniform_random
 from repro.graph.algorithms_ref import pagerank_ref, sssp_ref
@@ -22,7 +22,7 @@ MESHES = [(4, 2), (2, 4), (2, 2), (8, 1), (1, 8), (2, 1), (1, 2)]
 
 
 def _mesh(r, c):
-    return jax.make_mesh((r, c), ("data", "model"))
+    return dist.make_mesh((r, c), ("data", "model"))
 
 
 @pytest.fixture(scope="module")

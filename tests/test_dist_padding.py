@@ -73,9 +73,9 @@ POISON = np.int32(-777777)
 def _run_exchange(full_prev, blk, own_ids, mesh, frac, skip_empty):
     def body(fp, b, o):
         return rtd.exchange(fp, b[0], o[0], frac, skip_empty=skip_empty)
-    fn = jax.jit(rtd.shard_map(body, mesh=mesh,
+    fn = jax.jit(jax.shard_map(body, mesh=mesh,
                                in_specs=(P(), P("data"), P("data")),
-                               out_specs=(P(), P())))
+                               out_specs=(P(), P()), check_vma=False))
     return fn(full_prev, blk, own_ids)
 
 

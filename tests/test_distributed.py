@@ -5,7 +5,6 @@ test_dist_agree.py / test_dist_padding.py / test_property.py; this module
 pins the paper-faithful baseline plus the beyond-paper 2-D and
 pod-parallel paths.
 """
-import jax
 import numpy as np
 import pytest
 
@@ -69,19 +68,19 @@ def test_sssp_1d_road(mesh8):
 
 
 def test_sssp_2d(g, eight_devices):
-    mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh2 = dist.make_mesh((4, 2), ("data", "model"))
     assert np.array_equal(np.asarray(sssp_2d(g, mesh2, 0)),
                           sssp_ref(g, 0).astype(np.int32))
 
 
 def test_pr_2d(g, eight_devices):
-    mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh2 = dist.make_mesh((4, 2), ("data", "model"))
     assert np.allclose(np.asarray(pagerank_2d(g, mesh2)), pagerank_ref(g),
                        atol=1e-5)
 
 
 def test_bc_pod_parallel(g, eight_devices):
-    mesh3 = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh3 = dist.make_mesh((2, 4), ("pod", "data"))
     p = compile_bundled("bc", backend="distributed")
     srcs4 = np.array([0, 7, 23, 41], np.int32)
     out = dist.run_pod_parallel(p, g, mesh3, srcs4)

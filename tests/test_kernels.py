@@ -102,7 +102,7 @@ def test_sliced_bucket_kernel_path(g_skewed, monkeypatch):
     would leave the real kernel dispatch (block sizing, x blockspec of
     length n+1) untested until first TPU contact."""
     from repro.kernels.ell_spmv import ops as kops
-    monkeypatch.setattr(kops, "_USE_KERNEL", True)
+    monkeypatch.setattr(kops, "_use_kernel", lambda: True)
     g = g_skewed
     ell = prepare_sliced_ell(g, reverse=True)
     dist = jnp.full((g.num_nodes,), INF_I32, jnp.int32).at[0].set(0)

@@ -70,7 +70,7 @@ def test_elastic_checkpoint_restore_other_mesh():
     cfg = ARCHS["qwen2.5-3b"].smoke()
     m = build(cfg)
     state = init_state(m, jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     shardings = jax.tree.map(lambda _: NamedSharding(mesh, P()), state)
     with tempfile.TemporaryDirectory() as d:
         ckpt.save(d, 0, state)

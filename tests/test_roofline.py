@@ -52,8 +52,7 @@ def test_xla_cost_analysis_counts_bodies_once():
     def scanned(x, ws):
         return jax.lax.scan(lambda c, w: (c @ w, None), x, ws)[0]
     comp = _compile(scanned, x, ws)
-    from repro.launch.hlo_cost import xla_cost_dict
-    assert xla_cost_dict(comp)["flops"] < 2 * 128 ** 3 * 2   # ~1 body
+    assert comp.cost_analysis()["flops"] < 2 * 128 ** 3 * 2   # ~1 body
 
 
 def test_data_dependent_while_flagged():

@@ -431,6 +431,31 @@ def test_service_warm_reloads_tuned_schedule(tmp_path, g_a):
     asyncio.run(main())
 
 
+def test_service_ignores_record_tuned_on_another_device(tmp_path, g_a):
+    """A record timed on another kind of device (here: a TPU, while this
+    process computes elsewhere) neither warm-starts the serving schedule
+    nor seeds the tuner's cost model."""
+    from repro.autotune import device_kind, nearest_record
+    other = "TPU v5 lite" if device_kind() != "TPU v5 lite" else "cpu"
+    digest = source_digest(load_program_source("sssp"))
+    rec = dataclasses.replace(
+        _record(digest, get_context(g_a).fingerprint(),
+                Schedule(direction="pull", batch_sources=4)),
+        device_kind=other, graph_stats=dict(get_context(g_a).stats()))
+    store = TuningStore(str(tmp_path / "t.json"))
+    store.put(rec)
+    store.save()
+    store = TuningStore(str(tmp_path / "t.json"))
+    assert store.records() == [rec]                 # kept, under its own kind
+    assert store.lookup(digest, "local", rec.graph_fingerprint) is None
+    assert nearest_record(store, digest, "local", rec.graph_stats) is None
+
+    svc = GraphService(ServiceConfig(backend="local"), tune_store=store)
+    h = svc.register_graph("a", g_a, kinds=["sssp"])
+    assert h.tuned == []
+    assert h.schedules["sssp"] == Schedule()
+
+
 def test_register_graph_rejects_duplicates_and_unknown_kind(g_a):
     svc = GraphService()
     svc.register_graph("a", g_a, kinds=["sssp"])
