@@ -15,6 +15,7 @@ import hashlib
 from dataclasses import dataclass, field
 from typing import Dict
 
+from ...trace import span
 from ..parser import parse
 from ..semantic import analyze as semantic_analyze
 from .diagnostics import (ERROR, REGISTRY, SEVERITIES, WARNING, Diagnostic,
@@ -58,11 +59,12 @@ def program_analysis(source: str) -> ProgramAnalysis:
     hit = _CACHE.get(digest)
     if hit is not None:
         return hit
-    prog = parse(source)
-    infos = semantic_analyze(prog)
-    pa = ProgramAnalysis(source=source, functions={
-        fn.name: analyze_function(fn, infos[fn.name], source)
-        for fn in prog.functions})
+    with span("compile.analysis"):
+        prog = parse(source)
+        infos = semantic_analyze(prog)
+        pa = ProgramAnalysis(source=source, functions={
+            fn.name: analyze_function(fn, infos[fn.name], source)
+            for fn in prog.functions})
     _CACHE[digest] = pa
     return pa
 

@@ -28,6 +28,7 @@ import jax
 
 from ..graph.csr import resolve_schedule
 from ..schedule import Schedule
+from ..trace import device_counters, span
 from . import runtime as rt
 from .analysis import (DiagnosticError, check_schedule, entry_error,
                        program_analysis, split)
@@ -125,6 +126,10 @@ class BoundProgram:
     signature and cached here."""
 
     def __init__(self, program: CompiledProgram, graph, *, mesh=None):
+        with span("bind"):
+            self._bind(program, graph, mesh)
+
+    def _bind(self, program: CompiledProgram, graph, mesh):
         self.program = program
         self.graph = graph
         ctx = get_context(graph)
@@ -146,12 +151,20 @@ class BoundProgram:
                 ctx.delta_ell()   # warm the delta-stepping compact-relax view
 
     def __call__(self, **params):
+        """One run. Its `run` span times the dispatch (the first call also
+        traces and compiles) and keeps the result's device counters,
+        unfetched, on its record (`repro.trace`)."""
         prog = self.program
-        if prog.backend != "distributed":
-            return prog.fn(self.graph, **params)
-        from . import dist
-        return dist.run_prepared(prog, self._gd, self.mesh,
-                                 num_nodes=self.graph.num_nodes, **params)
+        with span("run") as rec:
+            if prog.backend != "distributed":
+                out = prog.fn(self.graph, **params)
+            else:
+                from . import dist
+                out = dist.run_prepared(prog, self._gd, self.mesh,
+                                        num_nodes=self.graph.num_nodes,
+                                        **params)
+            rec["counters"] = device_counters(out)
+        return out
 
     def refresh(self, prev: dict, delta, /, **params):
         # prev/delta are positional-only: program params are free to reuse
@@ -311,28 +324,27 @@ def compile_program(source: str, backend: str = "local",
         if cached is not None:
             return cached
 
-    prog_ast = parse(source)
-    irfns = lower(prog_ast)
+    with span("compile.parse"):
+        irfns = lower(parse(source))
     if fn_name is None:
         irfn = irfns[0]
     else:
         irfn = [f for f in irfns if f.name == fn_name][0]
-
-    if backend == "local":
-        from .codegen.local_jax import generate_local
-        body = generate_local(irfn, schedule=sched, **backend_opts)
-        extra_env = None
-    elif backend == "distributed":
-        from .codegen.distributed import generate_distributed
-        body, extra_env = generate_distributed(irfn, schedule=sched,
-                                               **backend_opts)
-    else:
-        from .codegen.pallas_backend import generate_pallas
-        body, extra_env = generate_pallas(irfn, schedule=sched,
-                                          **backend_opts)
-
-    src = _PRELUDE + body
-    env = _exec_generated(src, irfn.name, extra_env)
+    with span("compile.codegen"):
+        if backend == "local":
+            from .codegen.local_jax import generate_local
+            body = generate_local(irfn, schedule=sched, **backend_opts)
+            extra_env = None
+        elif backend == "distributed":
+            from .codegen.distributed import generate_distributed
+            body, extra_env = generate_distributed(irfn, schedule=sched,
+                                                   **backend_opts)
+        else:
+            from .codegen.pallas_backend import generate_pallas
+            body, extra_env = generate_pallas(irfn, schedule=sched,
+                                              **backend_opts)
+        src = _PRELUDE + body
+        env = _exec_generated(src, irfn.name, extra_env)
     raw = env[irfn.name]
     raw_refresh = env.get(f"{irfn.name}__refresh")
 

@@ -130,6 +130,8 @@ class DistCodegen(LocalCodegen):
     # sequential per-source fallback: fused lanes would need shard-uniform
     # per-lane trip counts threaded through every BSP superstep
     supports_batched_scalar_loops = False
+    # the BSP loops return `_gather_elems`, not the local superstep counters
+    superstep_counters = False
 
     def __init__(self, irfn: I.IRFunction, schedule=None):
         super().__init__(irfn, schedule=schedule)

@@ -9,6 +9,7 @@ the Min/Max construct → deterministic scatter-min (the paper's CAS atomics,
 """
 from __future__ import annotations
 
+import contextlib
 from typing import List, Optional
 
 from .. import ir as I
@@ -24,6 +25,15 @@ _JNP_DTYPE = {"int32": "jnp.int32", "bool": "jnp.bool_",
 # float64 → float32: x64 is disabled on TPU; sigma counts fit f32 for our sizes.
 
 _RED = {"+": "+", "-": "-", "*": "*", "/": "/", "&&": "&", "||": "|"}
+
+# Device counters carried through every top-level loop and returned as
+# result keys (a result key that starts with `_` is a device counter, see
+# `repro.trace`): loop bodies run; supersteps whose frontier relax pushed;
+# out-edges of the relax's frontier vertices; edges its chosen branch swept.
+# Edge counts are float32, as the distributed `_gather_elems` is: a run
+# can total past 2^31.
+COUNTERS = (("_supersteps", "jnp.int32"), ("_push_steps", "jnp.int32"),
+            ("_edges_active", "jnp.float32"), ("_edges_swept", "jnp.float32"))
 
 
 class LocalCodegen:
@@ -41,6 +51,8 @@ class LocalCodegen:
     # sequential per-source fallback instead (its BSP supersteps would need
     # shard-uniform trip counts per lane)
     supports_batched_scalar_loops = True
+    # top-level loops carry and return the `COUNTERS`
+    superstep_counters = True
 
     def __init__(self, irfn: I.IRFunction, schedule: Optional[Schedule] = None,
                  batch_sources: Optional[int] = None):
@@ -56,6 +68,9 @@ class LocalCodegen:
         #                                    per source; [B] when batched)
         self._delta_prop = None            # Min-relax prop of the active
         #                                    delta-stepping fixedPoint
+        self._top = None                   # the top-level statement emitting
+        self._scope = None                 # innermost loop's named scope
+        self._count = False                # relaxes here add to COUNTERS
         # every engine knob is baked into the emitted source as a literal:
         # same Schedule -> byte-identical source, and nothing generated ever
         # reads the deprecated ENGINE singleton at run time
@@ -206,16 +221,50 @@ class LocalCodegen:
                         em.w(f"{p.name} = rt.init_prop(N, {self.jdt(p.dtype)!s})")
                 elif p.kind == "scalar":
                     self.dtypes[p.name] = p.dtype
+            counters = self.superstep_counters and has_refresh_variant(f)
+            if counters:
+                for c, dt in COUNTERS:
+                    em.w(f"{c} = {dt}(0)")
             warm_pending = self.refresh_variant
             for s in f.body:
                 if warm_pending and isinstance(
                         s, (I.IFixedPoint, I.IDoWhile, I.IWhile)):
                     self._emit_warm_start(s)
                     warm_pending = False
+                self._top = s
                 self.stmt(s, HostCtx())
-            rets = ", ".join(f"'{v}': {v}" for v in self.declared)
+            rets = self.declared + ([c for c, _ in COUNTERS] if counters else [])
+            rets = ", ".join(f"'{v}': {v}" for v in rets)
             em.w(f"return {{{rets}}}")
         return em.source()
+
+    def _counted_carry(self, s: I.IRStmt, carry: List[str]) -> bool:
+        """True when loop `s` is top-level and so carries the COUNTERS
+        (appended to `carry`)."""
+        counted = self.superstep_counters and s is self._top
+        if counted:
+            carry.extend(c for c, _ in COUNTERS)
+        return counted
+
+    @contextlib.contextmanager
+    def _loop_body(self, n: str, counted: bool):
+        """Emit the body of loop `n` inside `jax.named_scope("<n>.body")`
+        (stable op names in the compiled HLO); a counted loop's body ends by
+        counting its superstep, and only its own relaxes (not a nested
+        loop's) add to the counters."""
+        saved = self._scope, self._count
+        self._scope, self._count = n.lstrip("_"), counted
+        self.em.w(f"with jax.named_scope('{self._scope}.body'):")
+        try:
+            with self.em.block():
+                mark = len(self.em.lines)
+                yield
+                if counted:
+                    self.em.w("_supersteps = _supersteps + 1")
+                if len(self.em.lines) == mark:
+                    self.em.w("pass")
+        finally:
+            self._scope, self._count = saved
 
     def _emit_warm_start(self, s: I.IRStmt):
         """Warm-override block of a `__refresh` variant.
@@ -678,35 +727,86 @@ class LocalCodegen:
         g = self.f.graph_param
         sched = self.schedule
         new = em.uid("new")
+        unw = "" if weighted else ", weighted=False"
         if frontier is None:
-            em.w(f"{new} = rt.relax_minplus_hybrid({g}, {s.prop}, None"
-                 f"{'' if weighted else ', weighted=False'})")
+            em.w(f"{new} = rt.relax_minplus_hybrid({g}, {s.prop}, None{unw})")
+            self._count_relax(None, None, f"{g}.num_edges")
             return new
         if self._delta_prop == s.prop and self.supports_delta_ell:
-            em.w(f"{new} = rt.relax_minplus_delta({g}, {s.prop}, {frontier}, "
-                 f"_dell, max(min(N // 8, 4096), 32){self._engine_kwargs()}"
-                 f"{'' if weighted else ', weighted=False'})")
+            call = (f"rt.relax_minplus_delta({g}, {s.prop}, {frontier}, "
+                    f"_dell, max(min(N // 8, 4096), 32){self._engine_kwargs()}"
+                    f"{unw}")
+            if not self._count:
+                em.w(f"{new} = {call})")
+                return new
+            pushed, swept = em.uid("pushed"), em.uid("swept")
+            em.w(f"{new}, {pushed}, {swept} = {call}, counts=True)")
+            self._count_relax(frontier, pushed, swept)
             return new
-        wexp = lambda w: f" + {w}" if weighted else ""  # noqa: E731
         push, pull = em.uid("push"), em.uid("pull")
+        scope = self._scope or "relax"
         if sched.direction != "pull":
-            em.w(f"{push} = lambda _d: rt.scatter_min(_d, {g}.indices, "
-                 f"jnp.where({frontier}[{g}.edge_src], "
-                 f"_d[{g}.edge_src]{wexp(f'{g}.weights')}, rt.INF))")
+            em.w(f"def {push}(_d):")
+            with em.block():
+                em.w(f"with jax.named_scope('{scope}.push'):")
+                with em.block():
+                    w = f" + {g}.weights" if weighted else ""
+                    em.w(f"return rt.scatter_min(_d, {g}.indices, jnp.where("
+                         f"{frontier}[{g}.edge_src], _d[{g}.edge_src]{w}, "
+                         f"rt.INF))")
         if sched.direction != "push":
-            em.w(f"{pull} = lambda _d: jnp.minimum(_d, rt.segment_min("
-                 f"jnp.where({frontier}[{g}.rev_indices], "
-                 f"_d[{g}.rev_indices]{wexp(f'{g}.rev_weights')}, rt.INF), "
-                 f"{g}.rev_edge_dst, {self.VLEN}))")
+            em.w(f"def {pull}(_d):")
+            with em.block():
+                em.w(f"with jax.named_scope('{scope}.pull'):")
+                with em.block():
+                    em.w(f"return {self._relax_pull_expr(frontier, weighted)}")
+        push_swept, pull_swept = self._relax_swept(weighted)
         if sched.direction == "push":
             em.w(f"{new} = {push}({s.prop})")
+            self._count_relax(frontier, "1", push_swept)
         elif sched.direction == "pull":
             em.w(f"{new} = {pull}({s.prop})")
+            self._count_relax(frontier, None, pull_swept)
         else:
-            em.w(f"{new} = jax.lax.cond(rt.frontier_should_push({frontier}, "
-                 f"{self.VLEN}, {sched.push_threshold_frac!r}), "
-                 f"{push}, {pull}, {s.prop})")
+            pushed = em.uid("pushed")
+            em.w(f"{pushed} = rt.frontier_should_push({frontier}, "
+                 f"{self.VLEN}, {sched.push_threshold_frac!r})")
+            em.w(f"{new} = jax.lax.cond({pushed}, {push}, {pull}, {s.prop})")
+            self._count_relax(frontier, pushed, push_swept if
+                              push_swept == pull_swept else
+                              f"jnp.where({pushed}, {push_swept}, {pull_swept})")
         return new
+
+    def _relax_pull_expr(self, frontier: str, weighted: bool) -> str:
+        """Pull branch of the frontier relax on `_d`: segment-min over every
+        in-edge, sources masked to the frontier."""
+        g = self.f.graph_param
+        w = f" + {g}.rev_weights" if weighted else ""
+        return (f"jnp.minimum(_d, rt.segment_min(jnp.where("
+                f"{frontier}[{g}.rev_indices], _d[{g}.rev_indices]{w}, "
+                f"rt.INF), {g}.rev_edge_dst, {self.VLEN}))")
+
+    def _relax_swept(self, weighted: bool):
+        """Edges the push and the pull branch sweep, as source expressions
+        (both the whole CSR here)."""
+        e = f"{self.f.graph_param}.num_edges"
+        return e, e
+
+    def _count_relax(self, frontier, pushed, swept: str):
+        """Add one relax step to the counters, inside a counted loop:
+        `pushed` is its push predicate (None: it pulled), `swept` the
+        edges its branch swept. The active edges are the out-edges of the
+        frontier's vertices (all of them for a dense sweep)."""
+        if not self._count:
+            return
+        em = self.em
+        g = self.f.graph_param
+        if pushed is not None:
+            em.w(f"_push_steps = _push_steps + jnp.int32({pushed})")
+        active = (f"{g}.num_edges" if frontier is None else
+                  f"jnp.sum(jnp.where({frontier}, {g}.out_degree, 0))")
+        em.w(f"_edges_active = _edges_active + jnp.float32({active})")
+        em.w(f"_edges_swept = _edges_swept + jnp.float32({swept})")
 
     def s_IMinMaxUpdate(self, s: I.IMinMaxUpdate, ctx):
         em = self.em
@@ -801,6 +901,7 @@ class LocalCodegen:
         if delta is not None:
             em.w(f"{n}_bk = jnp.int32(0)")
             carry.append(f"{n}_bk")
+        counted = self._counted_carry(s, carry)
         pack = ", ".join(carry)
         em.w(f"def {n}_cond(_state):")
         with em.block():
@@ -809,25 +910,26 @@ class LocalCodegen:
         em.w(f"def {n}_body(_state):")
         with em.block():
             em.w(f"({pack},) = _state" if len(carry) == 1 else f"({pack}) = _state")
-            if delta is None:
-                em.w(f"{conv}_nxt = jnp.zeros_like({conv})")
-            else:
-                # delta-stepping: the sweep's frontier is the pending set
-                # restricted to the current bucket window; out-of-window
-                # pending vertices seed the next sweep's pending set
-                self._emit_delta_preamble(n, delta, conv)
-                em.w(f"{conv}_nxt = {n}_keep")
-            saved = dict(self.write_alias)
-            self.write_alias[conv] = f"{conv}_nxt"
-            prev_dprop = self._delta_prop
-            self._delta_prop = delta
-            try:
-                self.body(s.body, ctx)
-            finally:
-                self._delta_prop = prev_dprop
-                self.write_alias = saved
-            em.w(f"{conv} = {conv}_nxt")
-            self.emit_finished(s.var, conv)
+            with self._loop_body(n, counted):
+                if delta is None:
+                    em.w(f"{conv}_nxt = jnp.zeros_like({conv})")
+                else:
+                    # delta-stepping: the sweep's frontier is the pending set
+                    # restricted to the current bucket window; out-of-window
+                    # pending vertices seed the next sweep's pending set
+                    self._emit_delta_preamble(n, delta, conv)
+                    em.w(f"{conv}_nxt = {n}_keep")
+                saved = dict(self.write_alias)
+                self.write_alias[conv] = f"{conv}_nxt"
+                prev_dprop = self._delta_prop
+                self._delta_prop = delta
+                try:
+                    self.body(s.body, ctx)
+                finally:
+                    self._delta_prop = prev_dprop
+                    self.write_alias = saved
+                em.w(f"{conv} = {conv}_nxt")
+                self.emit_finished(s.var, conv)
             em.w(f"return ({pack},)" if len(carry) == 1 else f"return ({pack})")
         em.w(f"_state = jax.lax.while_loop({n}_cond, {n}_body, ({pack},))"
              if len(carry) == 1 else
@@ -870,6 +972,7 @@ class LocalCodegen:
                 raise CodegenError("do-while inside a batched source loop")
             return self._batched_scalar_loop(s, ctx, do_while=True)
         carry = self.carries(s.body)
+        counted = self._counted_carry(s, carry)
         pack = ", ".join(carry)
         n = em.uid("dw")
         first = f"{n}_first"
@@ -880,7 +983,8 @@ class LocalCodegen:
         em.w(f"def {n}_body(_state):")
         with em.block():
             em.w(f"({first}, {pack}) = _state")
-            self.body(s.body, ctx)
+            with self._loop_body(n, counted):
+                self.body(s.body, ctx)
             em.w(f"return (jnp.asarray(False), {pack})")
         em.w(f"_state = jax.lax.while_loop({n}_cond, {n}_body, (jnp.asarray(True), {pack}))")
         em.w(f"({first}, {pack}) = _state")
@@ -892,6 +996,7 @@ class LocalCodegen:
                 raise CodegenError("while inside a batched source loop")
             return self._batched_scalar_loop(s, ctx, do_while=False)
         carry = self.carries(s.body)
+        counted = self._counted_carry(s, carry)
         pack = ", ".join(carry)
         n = em.uid("wl")
         em.w(f"def {n}_cond(_state):")
@@ -901,7 +1006,8 @@ class LocalCodegen:
         em.w(f"def {n}_body(_state):")
         with em.block():
             em.w(f"({pack},) = _state" if len(carry) == 1 else f"({pack}) = _state")
-            self.body(s.body, ctx)
+            with self._loop_body(n, counted):
+                self.body(s.body, ctx)
             em.w(f"return ({pack},)" if len(carry) == 1 else f"return ({pack})")
         em.w(f"_state = jax.lax.while_loop({n}_cond, {n}_body, ({pack}{',' if len(carry) == 1 else ''}))")
         em.w(f"({pack},) = _state" if len(carry) == 1 else f"({pack}) = _state")

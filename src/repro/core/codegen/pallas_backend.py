@@ -84,24 +84,34 @@ class PallasCodegen(LocalCodegen):
     # ---- hot pattern 1: frontier relax → sliced-ELL hybrid kernel ------------
     def emit_relax_hybrid(self, s: I.IMinMaxUpdate, frontier,
                           weighted: bool = True):
-        """Same pattern the local backend detects, lowered to the kernel op:
-        per-bucket pull kernels over the reverse sliced-ELL view, or
-        scatter-push over the CSR edge arrays when the frontier is sparse
-        (the op owns the on-device occupancy switch). The compiled
-        schedule's threshold/direction are baked in as literals. Under
-        delta-stepping the frontier arriving here is already the bucketed
-        window, so the same kernel call applies unchanged. The unweighted
-        relax (CC) keeps the inherited inline jnp lowering — the min-plus
-        kernels are weighted."""
-        if not weighted:
+        """Same pattern the local backend detects, with the pull branch
+        lowered to the kernel op: per-bucket pull kernels over the reverse
+        sliced-ELL view, or scatter-push over the CSR edge arrays when the
+        frontier is sparse (the inherited on-device occupancy switch, with
+        the compiled schedule's threshold/direction baked in as literals).
+        Under delta-stepping the frontier arriving here is already the
+        bucketed window, so the same lowering applies unchanged. A dense
+        sweep (no frontier) is one kernel pull. The unweighted relax (CC)
+        keeps the inherited inline jnp lowering — the min-plus kernels are
+        weighted."""
+        if frontier is not None or not weighted:
             return super().emit_relax_hybrid(s, frontier, weighted)
-        em = self.em
-        g = self.f.graph_param
-        new = em.uid("new")
-        fr = frontier or "None"
-        em.w(f"{new} = kops.relax_minplus(_ell, {s.prop}, frontier={fr}, "
-             f"csr={g}{self._kernel_kwargs()})")
+        new = self.em.uid("new")
+        self.em.w(f"{new} = kops.relax_minplus(_ell, {s.prop}, frontier=None, "
+                  f"csr={self.f.graph_param}{self._kernel_kwargs()})")
+        self._count_relax(None, None, "_ell.padded_cells()")
         return new
+
+    def _relax_pull_expr(self, frontier: str, weighted: bool) -> str:
+        if not weighted:
+            return super()._relax_pull_expr(frontier, weighted)
+        return (f"kops.relax_minplus(_ell, _d, frontier={frontier}, "
+                f"csr={self.f.graph_param}, direction='pull', "
+                f"block_rows={self._block_rows_literal()})")
+
+    def _relax_swept(self, weighted: bool):
+        push, pull = super()._relax_swept(weighted)
+        return push, ("_ell.padded_cells()" if weighted else pull)
 
     # ---- hot pattern 2: neighborhood sum → sliced-ELL (+,×) kernel -----------
     def s_IAssign(self, s: I.IAssign, ctx):

@@ -43,6 +43,7 @@ import numpy as np
 from ..graph.csr import (CSRGraph, pad_nodes, resolve_schedule, to_ell,
                          to_sliced_ell)
 from ..schedule import Schedule
+from ..trace import span
 
 
 class GraphContext:
@@ -341,19 +342,20 @@ def prepare(g: CSRGraph, schedule: Optional[Schedule] = None, *,
             schedule = getattr(program, "schedule", None)
         backend = getattr(program, "backend", backend)
     sched = resolve_schedule(schedule)
-    ctx = get_context(g)
-    if backend == "pallas":
-        ctx.sliced_ell(sched, reverse=True)
-    elif backend == "distributed":
-        if mesh is None:
-            from .dist import make_mesh_1d
-            mesh = make_mesh_1d()
-        meta = (getattr(program, "dist_meta", None) or {})
-        ctx.dist_arrays(mesh, ell=meta.get("needs_ell", False))
-    elif backend != "local":
-        raise ValueError(
-            f"unknown backend {backend!r}; expected 'local', 'pallas', or "
-            "'distributed'")
+    with span("prepare"):
+        ctx = get_context(g)
+        if backend == "pallas":
+            ctx.sliced_ell(sched, reverse=True)
+        elif backend == "distributed":
+            if mesh is None:
+                from .dist import make_mesh_1d
+                mesh = make_mesh_1d()
+            meta = (getattr(program, "dist_meta", None) or {})
+            ctx.dist_arrays(mesh, ell=meta.get("needs_ell", False))
+        elif backend != "local":
+            raise ValueError(
+                f"unknown backend {backend!r}; expected 'local', 'pallas', or "
+                "'distributed'")
     return ctx
 
 

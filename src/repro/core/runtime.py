@@ -184,7 +184,7 @@ def relax_minplus_hybrid(g: CSRGraph, dist: jax.Array,
                          frontier: jax.Array | None = None,
                          threshold_frac: float | None = None,
                          direction: str = "auto",
-                         weighted: bool = True) -> jax.Array:
+                         weighted: bool = True, counts: bool = False):
     """One SSSP/min-plus relaxation restricted to `frontier` sources, with
     push/pull direction chosen on-device.
 
@@ -195,7 +195,9 @@ def relax_minplus_hybrid(g: CSRGraph, dist: jax.Array,
     exactly, so the switch never changes results. `frontier=None` is a dense
     sweep (every vertex contributes). `weighted=False` drops the `+ w` term
     (the candidate is just dist[u]) — the unweighted Min relax of connected
-    components, which takes the same push/pull machinery.
+    components, which takes the same push/pull machinery. `counts=True`
+    also returns whether the step pushed (a bool scalar) and the edges it
+    swept (E, float32), for the superstep counters of generated code.
 
     NOTE: this push/pull relaxation pair exists in four places — here, the
     batched form below (`relax_minplus_hybrid_batch`), the kernel-backed
@@ -218,14 +220,14 @@ def relax_minplus_hybrid(g: CSRGraph, dist: jax.Array,
             cand = jnp.where(frontier[g.rev_indices], cand, INF)
         return jnp.minimum(d, segment_min(cand, g.rev_edge_dst, n))
 
-    if frontier is None:
-        return pull(dist)
-    if direction == "push":
-        return push(dist)
-    if direction == "pull":
-        return pull(dist)
-    return jax.lax.cond(frontier_should_push(frontier, n, threshold_frac),
-                        push, pull, dist)
+    if frontier is None or direction == "pull":
+        out, pushed = pull(dist), jnp.bool_(False)
+    elif direction == "push":
+        out, pushed = push(dist), jnp.bool_(True)
+    else:
+        pushed = frontier_should_push(frontier, n, threshold_frac)
+        out = jax.lax.cond(pushed, push, pull, dist)
+    return (out, pushed, jnp.float32(g.num_edges)) if counts else out
 
 
 # --- delta-stepping (priority-bucketed) relaxation -----------------------------
@@ -243,7 +245,7 @@ def relax_minplus_delta(g: CSRGraph, dist: jax.Array, frontier: jax.Array,
                         ell=None, cap: int | None = None,
                         threshold_frac: float | None = None,
                         direction: str = "auto",
-                        weighted: bool = True) -> jax.Array:
+                        weighted: bool = True, counts: bool = False):
     """One bucketed min relaxation over `frontier` sources (the caller has
     already restricted the frontier to the current delta bucket).
 
@@ -254,10 +256,12 @@ def relax_minplus_delta(g: CSRGraph, dist: jax.Array, frontier: jax.Array,
     unused slots are masked to INF and scattered out of bounds, which XLA
     drops. Overflowing frontiers — and `ell=None` (hub-heavy graphs where
     max_deg makes the ELL view uneconomical) — fall back to the dense
-    hybrid sweep, which computes the same relaxation."""
+    hybrid sweep, which computes the same relaxation. `counts=True` also
+    returns (pushed, swept) as `relax_minplus_hybrid` does; the compact
+    path is a push that sweeps its cap x ELL-width slots."""
     if ell is None or cap is None or cap <= 0:
         return relax_minplus_hybrid(g, dist, frontier, threshold_frac,
-                                    direction, weighted)
+                                    direction, weighted, counts)
     n = g.num_nodes
     cap = int(min(cap, n))
 
@@ -275,11 +279,12 @@ def relax_minplus_delta(g: CSRGraph, dist: jax.Array, frontier: jax.Array,
             else jnp.broadcast_to(src, cols.shape)
         cand = jnp.where(valid, cand, INF)
         tgt = jnp.where(valid, cols, n)                       # n → dropped
-        return d.at[tgt.ravel()].min(cand.ravel())
+        out = d.at[tgt.ravel()].min(cand.ravel())
+        return (out, jnp.bool_(True), jnp.float32(cols.size)) if counts else out
 
     def dense(d):
         return relax_minplus_hybrid(g, d, frontier, threshold_frac,
-                                    direction, weighted)
+                                    direction, weighted, counts)
 
     return jax.lax.cond(frontier_size(frontier) <= jnp.int32(cap),
                         compact, dense, dist)

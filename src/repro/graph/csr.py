@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..schedule import DEFAULT_SCHEDULE, Schedule
+from ..trace import span
 
 INF_I32 = np.int32(2**30)  # "infinity" that survives + weight without overflow
 
@@ -163,12 +164,13 @@ class CSRGraph:
 
 
 def _build_csr(n: int, src: np.ndarray, dst: np.ndarray, w: np.ndarray):
-    order = np.lexsort((dst, src))
-    src, dst, w = src[order], dst[order], w[order]
-    indptr = np.zeros(n + 1, np.int64)
-    np.add.at(indptr, src + 1, 1)
-    indptr = np.cumsum(indptr)
-    return indptr.astype(np.int32), dst.astype(np.int32), w.astype(np.int32), src.astype(np.int32)
+    with span("graph.csr"):
+        order = np.lexsort((dst, src))
+        src, dst, w = src[order], dst[order], w[order]
+        indptr = np.zeros(n + 1, np.int64)
+        np.add.at(indptr, src + 1, 1)
+        indptr = np.cumsum(indptr)
+        return indptr.astype(np.int32), dst.astype(np.int32), w.astype(np.int32), src.astype(np.int32)
 
 
 def from_edges(
@@ -194,9 +196,10 @@ def from_edges(
         keep = src != dst
         src, dst, w = src[keep], dst[keep], w[keep]
     if dedup and len(src):
-        key = src * np.int64(n) + dst
-        _, first = np.unique(key, return_index=True)
-        src, dst, w = src[first], dst[first], w[first]
+        with span("graph.dedup"):
+            key = src * np.int64(n) + dst
+            _, first = np.unique(key, return_index=True)
+            src, dst, w = src[first], dst[first], w[first]
     e = len(src)
     indptr, indices, w_s, edge_src = _build_csr(n, src, dst, w)
     rev_indptr, rev_indices, rev_w, rev_edge_dst = _build_csr(n, dst, src, w)
@@ -205,18 +208,21 @@ def from_edges(
     # CSR order is lexsorted by (src, dst), so the key array is sorted by
     # construction; int64 intermediate avoids silent wrap while building.
     edge_key = (edge_src.astype(np.int64) * n + indices.astype(np.int64)).astype(np.int32)
+    with span("graph.to_device"):
+        arrays = dict(
+            indptr=jnp.asarray(indptr),
+            indices=jnp.asarray(indices),
+            weights=jnp.asarray(w_s),
+            edge_src=jnp.asarray(edge_src),
+            rev_indptr=jnp.asarray(rev_indptr),
+            rev_indices=jnp.asarray(rev_indices),
+            rev_weights=jnp.asarray(rev_w),
+            rev_edge_dst=jnp.asarray(rev_edge_dst),
+            out_degree=jnp.asarray(out_deg),
+            in_degree=jnp.asarray(in_deg),
+            edge_key=jnp.asarray(edge_key))
     return CSRGraph(
-        indptr=jnp.asarray(indptr),
-        indices=jnp.asarray(indices),
-        weights=jnp.asarray(w_s),
-        edge_src=jnp.asarray(edge_src),
-        rev_indptr=jnp.asarray(rev_indptr),
-        rev_indices=jnp.asarray(rev_indices),
-        rev_weights=jnp.asarray(rev_w),
-        rev_edge_dst=jnp.asarray(rev_edge_dst),
-        out_degree=jnp.asarray(out_deg),
-        in_degree=jnp.asarray(in_deg),
-        edge_key=jnp.asarray(edge_key),
+        **arrays,
         num_nodes=int(n),
         num_edges=int(e),
         max_out_degree=int(out_deg.max(initial=1)),

@@ -2,7 +2,8 @@
 
 The TPU compiler is installed with JAX and compiles for a chip that is
 described, not attached. These tests compile the per-iteration kernels of
-the local backend at the shapes of an RMAT scale-21 graph (the size
+the local backend, and its whole generated `sssp` and `pr` programs, at
+the shapes of an RMAT scale-21 graph (the size
 `chip_smoke.py` runs) and check they fit one v5e's 16 GB, and they pin
 what Mosaic answers for the Pallas ELL kernel. Nothing runs, so they say
 nothing about results or times.
@@ -85,6 +86,23 @@ def test_pagerank_reverse_edge_sum_fits_one_chip(one_chip):
     compiled = pull.lower(_shape(one_chip, (PR_LANES, E), jnp.float32),
                           _graph(one_chip)).compile()
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("name,params,scopes", [
+    ("sssp", dict(src=jnp.int32), ("fp1.body", "fp1.push", "fp1.pull")),
+    ("pr", dict(beta=jnp.float32, delta=jnp.float32, maxIter=jnp.int32),
+     ("dw1.body",)),
+])
+def test_generated_program_fits_one_chip(one_chip, name, params, scopes):
+    """The whole generated program, superstep counters included: it fits
+    one v5e, its HLO names the loop and relax scopes, and it holds no host
+    callback."""
+    args = {k: _shape(one_chip, (), dt) for k, dt in params.items()}
+    compiled = compile_bundled(name).fn.lower(_graph(one_chip), **args).compile()
+    text = compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES
+    assert all(s in text for s in scopes), scopes
+    assert "callback" not in text.lower()
 
 
 @pytest.mark.parametrize("lanes,error", [(None, MOSAIC_ERRORS[0]),
