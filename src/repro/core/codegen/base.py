@@ -172,32 +172,48 @@ def relax_candidate(cand, other_side: str):
     return None
 
 
-def pure_vertex_predicate(expr, side: str) -> bool:
-    """True if `expr` reads only <side>.prop, constants, and host scalars —
-    i.e. it can be evaluated once as an [N] vertex mask instead of per edge.
-    Rejects edge weights, foreign iterators, and vertex-local scalars (which
-    are aligned to the *outer* vertex, not `side`)."""
-    ok = True
+def _subexprs(e):
+    yield e
+    if isinstance(e, I.IBin):
+        yield from _subexprs(e.left)
+        yield from _subexprs(e.right)
+    elif isinstance(e, I.IUn):
+        yield from _subexprs(e.operand)
+    elif isinstance(e, I.ICall):
+        for a in e.args:
+            yield from _subexprs(a)
 
-    def visit(e):
-        nonlocal ok
-        if isinstance(e, I.IProp):
-            if e.target != side:
-                ok = False
-        elif isinstance(e, (I.IEdgeWeight, I.IVertexLocal)):
-            ok = False
-        elif isinstance(e, I.IIterId) and e.name != side:
-            ok = False
-        elif isinstance(e, I.IBin):
-            visit(e.left); visit(e.right)
-        elif isinstance(e, I.IUn):
-            visit(e.operand)
-        elif isinstance(e, I.ICall):
-            for a in e.args:
-                visit(a)
 
-    visit(expr)
-    return ok
+def only_reads_side(expr, side: str) -> bool:
+    """True if `expr` reads only <side>.prop, degrees of <side>, constants
+    and host scalars — i.e. it can be evaluated once as an [N] vertex array
+    (a neighbor filter's mask, a neighbor-only reduction term) instead of
+    per edge. Rejects edge weights, foreign iterators, and vertex-local
+    scalars (which are aligned to the *outer* vertex, not `side`)."""
+    for e in _subexprs(expr):
+        if isinstance(e, I.IProp) and e.target != side:
+            return False
+        if isinstance(e, (I.IEdgeWeight, I.IVertexLocal)):
+            return False
+        if isinstance(e, I.IIterId) and e.name != side:
+            return False
+    return True
+
+
+def side_term(expr, side: str) -> bool:
+    """True if `expr` depends on the vertex `side` and on nothing else that
+    varies per edge: `only_reads_side`, and it reads <side> at all (a
+    constant term has no vertex array to gather)."""
+    return only_reads_side(expr, side) and any(
+        (isinstance(e, I.IProp) and e.target == side)
+        or (isinstance(e, I.IIterId) and e.name == side)
+        for e in _subexprs(expr))
+
+
+def reads_props(expr, props) -> bool:
+    """True if `expr` reads a property named in `props`."""
+    return any(isinstance(e, I.IProp) and e.prop in props
+               for e in _subexprs(expr))
 
 
 class ExprEmitter:

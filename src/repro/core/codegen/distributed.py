@@ -54,7 +54,7 @@ import contextlib
 from .. import ir as I
 from ..ir import read_props
 from .base import (BFSCtx, CodegenError, EdgeCtx, ExprEmitter, HostCtx,
-                   VertexCtx, pure_vertex_predicate, relax_candidate)
+                   VertexCtx, only_reads_side, relax_candidate)
 from .local_jax import LocalCodegen
 
 # Ablation switch for the loop-invariant gather hoist: properties a BSP
@@ -190,6 +190,11 @@ class DistCodegen(LocalCodegen):
         return em.source()
 
     # ------------------------------------------------------------------ helpers
+    def _edge_term(self, expr, ctx) -> str:
+        # a term's neighbor operands read the exchanged `{p}_full` buffers
+        # by global id; the shard's own [B] vertex block cannot stand in
+        return self.ex.expr(expr, ctx)
+
     def fidx(self, arr: str, idx: str) -> str:
         """Index a replicated full array by an id array, batch-aware."""
         if self.batch is not None and arr in self.batch.arrays:
@@ -431,7 +436,7 @@ class DistCodegen(LocalCodegen):
             terms.append(self.fidx(mf, ectx.vid))
             ectx.src_vmask = mf
         if s.filter is not None:
-            if pure_vertex_predicate(s.filter, s.it):
+            if only_reads_side(s.filter, s.it):
                 # neighbor-side filter that only reads nbr-props: hoist it
                 # to one full vertex mask (the frontier the engine and the
                 # direction switch consume)

@@ -10,6 +10,7 @@ the Min/Max construct → deterministic scatter-min (the paper's CAS atomics,
 from __future__ import annotations
 
 import contextlib
+import re
 from typing import List, Optional
 
 from .. import ir as I
@@ -17,8 +18,8 @@ from ...graph.csr import resolve_schedule
 from ...schedule import Schedule
 from ..ir import written_vars
 from .base import (BatchInfo, BFSCtx, CodegenError, EdgeCtx, Emitter,
-                   ExprEmitter, HostCtx, VertexCtx, ctx_chain,
-                   pure_vertex_predicate, relax_candidate)
+                   ExprEmitter, HostCtx, VertexCtx, ctx_chain, only_reads_side,
+                   reads_props, relax_candidate, side_term)
 
 _JNP_DTYPE = {"int32": "jnp.int32", "bool": "jnp.bool_",
               "float32": "jnp.float32", "float64": "jnp.float32"}
@@ -110,6 +111,22 @@ class LocalCodegen:
         else:
             self.em.w(f"{m} = {expr}")
         return m
+
+    def _edge_term(self, expr: I.IRExpr, ctx) -> str:
+        """Source of a reduction term `expr`, per edge inside a neighbor
+        loop. A term that depends only on the neighbor vertex (its props,
+        its degrees, scalars) is evaluated once as a vertex array and
+        gathered with the loop's neighbor-id array, so the edge sweep
+        gathers once however many neighbor operands the term reads."""
+        ectx = self._edge_ctx(ctx)
+        if ectx is None or not side_term(expr, ectx.it):
+            return self.ex.expr(expr, ctx)
+        term = self.em.uid("nt")
+        per_vertex = VertexCtx(it=ectx.it, mask=None, parent=ctx)
+        self.em.w(f"{term} = {self.ex.expr(expr, per_vertex)}")
+        if self.batch is not None and reads_props(expr, self.batch.arrays):
+            self.batch.arrays.add(term)
+        return self.bg(term, ectx.nid)
 
     def _snapshot(self):
         return (len(self.em.lines), self.em._uid, list(self.declared),
@@ -391,7 +408,7 @@ class LocalCodegen:
 
     def s_IAssign(self, s: I.IAssign, ctx):
         em = self.em
-        e = self.ex.expr(s.expr, ctx)
+        e = self._edge_term(s.expr, ctx)
         dt = self.dtype_of(s.name)
         cast = (lambda x: f"jnp.asarray({x}, {self.jdt(dt)})") if dt else (lambda x: x)
         vctx = self._vertex_ctx(ctx)
@@ -546,7 +563,7 @@ class LocalCodegen:
             terms.append(self.bg(vctx.mask, ectx.vid))
             ectx.src_vmask = vctx.mask
         if s.filter is not None:
-            if pure_vertex_predicate(s.filter, s.it):
+            if only_reads_side(s.filter, s.it):
                 # neighbor-side filter that only reads nbr-props: hoist it to
                 # one [N] vertex mask (the frontier the engine switches on)
                 nm = self._vmask(
@@ -559,9 +576,14 @@ class LocalCodegen:
         ectx.pure_frontier = pure
         if terms:
             mask = em.uid("em")
+            at = len(em.lines)
             em.w(f"{mask} = {' & '.join(terms)}")
             ectx.mask = mask
         self.body(s.body, ectx)
+        if terms and not any(re.search(rf"\b{mask}\b", line)
+                             for line in em.lines[at + 1:]):
+            # the frontier relax reads the vertex frontier, not the mask
+            del em.lines[at]
 
     def _bfs_nbr_loop(self, s: I.INbrLoop, ctx, bctx: BFSCtx):
         """neighbors() inside iterateInBFS = BFS-DAG successors (paper §2.3.2)."""
@@ -590,7 +612,7 @@ class LocalCodegen:
         ectx = self._edge_ctx(ctx)
         vctx = self._vertex_ctx(ctx)
         p = self.wtarget(s.prop)
-        e = self.ex.expr(s.expr, ctx)
+        e = self._edge_term(s.expr, ctx)
         if self.batch is not None:
             return self._batched_assign_prop(s, ectx, vctx, p, e)
         if ectx is not None:
@@ -750,16 +772,16 @@ class LocalCodegen:
             with em.block():
                 em.w(f"with jax.named_scope('{scope}.push'):")
                 with em.block():
-                    w = f" + {g}.weights" if weighted else ""
-                    em.w(f"return rt.scatter_min(_d, {g}.indices, jnp.where("
-                         f"{frontier}[{g}.edge_src], _d[{g}.edge_src]{w}, "
-                         f"rt.INF))")
+                    cand = self._frontier_cand(frontier, f"{g}.edge_src",
+                                               weighted and f"{g}.weights")
+                    em.w(f"return rt.scatter_min(_d, {g}.indices, {cand})")
         if sched.direction != "push":
             em.w(f"def {pull}(_d):")
             with em.block():
                 em.w(f"with jax.named_scope('{scope}.pull'):")
                 with em.block():
-                    em.w(f"return {self._relax_pull_expr(frontier, weighted)}")
+                    ret = self._relax_pull_expr(frontier, weighted)
+                    em.w(f"return {ret}")
         push_swept, pull_swept = self._relax_swept(weighted)
         if sched.direction == "push":
             em.w(f"{new} = {push}({s.prop})")
@@ -777,14 +799,27 @@ class LocalCodegen:
                               f"jnp.where({pushed}, {push_swept}, {pull_swept})")
         return new
 
+    def _frontier_cand(self, frontier: str, idx: str, w) -> str:
+        """Relax candidates of `_d` along the edges whose sources `idx`
+        names, INF off the frontier: the frontier is folded into the
+        source value first (off-frontier vertices read `rt.SENT`, int32's
+        maximum), so the edge sweep gathers one vertex array. `w` is the
+        weight array, or False for the unweighted relax, whose SENT
+        candidates lose every min as INF would. Emits the gather; returns
+        the candidate expression."""
+        src = self.em.uid("src")
+        self.em.w(f"{src} = jnp.where({frontier}, _d, rt.SENT)[{idx}]")
+        return (f"jnp.where({src} == rt.SENT, rt.INF, {src} + {w})" if w
+                else src)
+
     def _relax_pull_expr(self, frontier: str, weighted: bool) -> str:
         """Pull branch of the frontier relax on `_d`: segment-min over every
-        in-edge, sources masked to the frontier."""
+        in-edge, sources masked to the frontier (may emit the gather)."""
         g = self.f.graph_param
-        w = f" + {g}.rev_weights" if weighted else ""
-        return (f"jnp.minimum(_d, rt.segment_min(jnp.where("
-                f"{frontier}[{g}.rev_indices], _d[{g}.rev_indices]{w}, "
-                f"rt.INF), {g}.rev_edge_dst, {self.VLEN}))")
+        cand = self._frontier_cand(frontier, f"{g}.rev_indices",
+                                   weighted and f"{g}.rev_weights")
+        return (f"jnp.minimum(_d, rt.segment_min({cand}, {g}.rev_edge_dst, "
+                f"{self.VLEN}))")
 
     def _relax_swept(self, weighted: bool):
         """Edges the push and the pull branch sweep, as source expressions
@@ -830,7 +865,7 @@ class LocalCodegen:
                 ev = self.ex.expr(eval_, HostCtx())
                 em.w(f"{ep} = jnp.where({upd}, {ev}, {ep})")
             return
-        cand = self.ex.expr(s.cand, ctx)
+        cand = self._edge_term(s.cand, ctx)
         cv = em.uid("cand")
         ident = f"rt.inf_for({self.jdt(dtype)})" if s.kind == "Min" else f"-rt.inf_for({self.jdt(dtype)})"
         if ectx.mask:

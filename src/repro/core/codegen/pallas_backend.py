@@ -24,33 +24,8 @@ already fuses well; the kernels own the compute-dense inner loops.
 from __future__ import annotations
 
 from .. import ir as I
-from .base import HostCtx, VertexCtx
+from .base import HostCtx, VertexCtx, only_reads_side
 from .local_jax import LocalCodegen, has_refresh_variant
-
-
-def _only_reads_side(expr, side: str) -> bool:
-    """True if expr reads only <side>.prop / degree(<side>) / constants."""
-    ok = True
-
-    def visit(e):
-        nonlocal ok
-        if isinstance(e, I.IProp):
-            if e.target != side:
-                ok = False
-        elif isinstance(e, I.IEdgeWeight):
-            ok = False
-        elif isinstance(e, I.IIterId) and e.name != side:
-            ok = False
-        elif isinstance(e, I.IBin):
-            visit(e.left); visit(e.right)
-        elif isinstance(e, I.IUn):
-            visit(e.operand)
-        elif isinstance(e, I.ICall):
-            for a in e.args:
-                visit(a)
-
-    visit(expr)
-    return ok
 
 
 class PallasCodegen(LocalCodegen):
@@ -121,7 +96,7 @@ class PallasCodegen(LocalCodegen):
         if (s.reduce_op == "+" and s.vertex_local and ectx is not None
                 and ectx.direction == "in" and ectx.mask is None
                 and self.batch is None and s.name not in self.lane_scalars
-                and _only_reads_side(s.expr, ectx.it)):
+                and only_reads_side(s.expr, ectx.it)):
             em = self.em
             contrib = em.uid("contrib")
             # evaluate the per-edge term as a per-NODE vector (nbr ↦ node)

@@ -17,6 +17,9 @@ import jax.numpy as jnp
 from ..graph.csr import CSRGraph, ENGINE, INF_I32
 
 INF = jnp.int32(INF_I32)
+# int32's maximum: what the generated integer relax reads at vertices off
+# its frontier before its one edge gather; never a distance
+SENT = jnp.int32(jnp.iinfo(jnp.int32).max)
 
 
 # --- scatter / segment combine (the Min/Max construct, reductions) -----------
@@ -180,6 +183,25 @@ def frontier_should_push(frontier: jax.Array, n: int,
     return frontier_size(frontier) <= jnp.int32(max(int(n * frac), 1))
 
 
+def _frontier_cand(d, frontier, idx, w=None):
+    """Relax candidates d[u] (+ w) along the edges whose sources `idx`
+    names, INF where u is off `frontier` (None: every vertex is on it).
+    d and frontier may be [N] or [B, N] (gathered along the last axis).
+    Integer d takes one gather: the frontier is folded into d first, off-
+    frontier vertices read SENT (the dtype's maximum), and a gathered SENT
+    becomes INF (unweighted, SENT itself: it loses every min as INF does).
+    Floating d gathers the mask and the value separately."""
+    if frontier is None:
+        src = d[..., idx]
+        return src if w is None else src + w
+    if jnp.issubdtype(d.dtype, jnp.integer):
+        sent = jnp.iinfo(d.dtype).max
+        src = jnp.where(frontier, d, sent)[..., idx]
+        return src if w is None else jnp.where(src == sent, INF, src + w)
+    cand = d[..., idx] if w is None else d[..., idx] + w
+    return jnp.where(frontier[..., idx], cand, INF)
+
+
 def relax_minplus_hybrid(g: CSRGraph, dist: jax.Array,
                          frontier: jax.Array | None = None,
                          threshold_frac: float | None = None,
@@ -204,20 +226,21 @@ def relax_minplus_hybrid(g: CSRGraph, dist: jax.Array,
     ops (kernels/ell_spmv/ops.py `_relax_push`/`_relax_sliced_pull`), and
     inline in the local backend's generated source
     (local_jax.emit_relax_hybrid, kept inline so the lowering stays
-    inspectable). A semantic change to any copy must be applied to all."""
+    inspectable). All four gather one vertex array per edge sweep: integer
+    distances fold the frontier into the source value first (off-frontier
+    sources read SENT, the dtype's maximum, and a gathered SENT becomes an
+    INF candidate), as `_frontier_cand` does; the sliced pull masks its
+    gather operand the same way, with INF. A semantic change to any copy
+    must be applied to all."""
     n = g.num_nodes
 
     def push(d):
-        cand = d[g.edge_src] + g.weights if weighted else d[g.edge_src]
-        if frontier is not None:
-            cand = jnp.where(frontier[g.edge_src], cand, INF)
-        return scatter_min(d, g.indices, cand)
+        return scatter_min(d, g.indices, _frontier_cand(
+            d, frontier, g.edge_src, g.weights if weighted else None))
 
     def pull(d):
-        cand = d[g.rev_indices] + g.rev_weights if weighted \
-            else d[g.rev_indices]
-        if frontier is not None:
-            cand = jnp.where(frontier[g.rev_indices], cand, INF)
+        cand = _frontier_cand(d, frontier, g.rev_indices,
+                              g.rev_weights if weighted else None)
         return jnp.minimum(d, segment_min(cand, g.rev_edge_dst, n))
 
     if frontier is None or direction == "pull":
@@ -383,17 +406,12 @@ def relax_minplus_hybrid_batch(g: CSRGraph, dist: jax.Array,
     n = g.num_nodes
 
     def push(d, fr):
-        cand = d[:, g.edge_src] + g.weights[None, :] if weighted \
-            else d[:, g.edge_src]
-        if fr is not None:
-            cand = jnp.where(fr[:, g.edge_src], cand, INF)
-        return scatter_min_rows(d, g.indices, cand)
+        return scatter_min_rows(d, g.indices, _frontier_cand(
+            d, fr, g.edge_src, g.weights if weighted else None))
 
     def pull(d, fr):
-        cand = d[:, g.rev_indices] + g.rev_weights[None, :] if weighted \
-            else d[:, g.rev_indices]
-        if fr is not None:
-            cand = jnp.where(fr[:, g.rev_indices], cand, INF)
+        cand = _frontier_cand(d, fr, g.rev_indices,
+                              g.rev_weights if weighted else None)
         return jnp.minimum(d, segment_min_batch(cand, g.rev_edge_dst, n))
 
     if frontier is None:

@@ -194,14 +194,17 @@ def _relax_sliced_pull(ell: SlicedEllGraph, dist, frontier=None,
 
 def _relax_push(g: CSRGraph, dist, frontier):
     """Scatter-push from the (sparse) frontier over out-edges.
-    dist/frontier: [N] or [B, N] (row-wise scatter-min)."""
-    if dist.ndim == 2:
-        cand = dist[:, g.edge_src] + g.weights[None, :]
-        cand = jnp.where(frontier[:, g.edge_src], cand, INF)
-        return dist.at[:, g.indices].min(cand)
-    cand = dist[g.edge_src] + g.weights
-    cand = jnp.where(frontier[g.edge_src], cand, INF)
-    return dist.at[g.indices].min(cand)
+    dist/frontier: [N] or [B, N] (row-wise scatter-min). Integer dist
+    gathers once: off-frontier sources read the dtype's maximum, which
+    becomes an INF candidate (runtime._frontier_cand's fold)."""
+    if jnp.issubdtype(dist.dtype, jnp.integer):
+        sent = jnp.iinfo(dist.dtype).max
+        src = jnp.where(frontier, dist, sent)[..., g.edge_src]
+        cand = jnp.where(src == sent, INF, src + g.weights)
+    else:
+        cand = jnp.where(frontier[..., g.edge_src],
+                         dist[..., g.edge_src] + g.weights, INF)
+    return dist.at[..., g.indices].min(cand)
 
 
 def relax_minplus(cols_or_ell, wts_or_dist, dist=None, *, frontier=None,

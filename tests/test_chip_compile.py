@@ -4,8 +4,9 @@ The TPU compiler is installed with JAX and compiles for a chip that is
 described, not attached. These tests compile the per-iteration kernels of
 the local backend, and its whole generated `sssp` and `pr` programs, at
 the shapes of an RMAT scale-21 graph (the size
-`chip_smoke.py` runs) and check they fit one v5e's 16 GB, and they pin
-what Mosaic answers for the Pallas ELL kernel. Nothing runs, so they say
+`chip_smoke.py` runs) and check they fit one v5e's 16 GB and that each
+neighbor loop gathers over the edges once, and they pin what Mosaic
+answers for the Pallas ELL kernel. Nothing runs, so they say
 nothing about results or times.
 
 The topology is described inside a module fixture, never at import: only
@@ -14,6 +15,7 @@ workers only the worker given this file may try, and every worker must
 collect the same tests.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -88,21 +90,87 @@ def test_pagerank_reverse_edge_sum_fits_one_chip(one_chip):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
-@pytest.mark.parametrize("name,params,scopes", [
+PROGRAMS = [
     ("sssp", dict(src=jnp.int32), ("fp1.body", "fp1.push", "fp1.pull")),
     ("pr", dict(beta=jnp.float32, delta=jnp.float32, maxIter=jnp.int32),
      ("dw1.body",)),
-])
+]
+
+
+def _compile_program(sharding, name, params):
+    args = {k: _shape(sharding, (), dt) for k, dt in params.items()}
+    return compile_bundled(name).fn.lower(_graph(sharding), **args).compile()
+
+
+@pytest.mark.parametrize("name,params,scopes", PROGRAMS)
 def test_generated_program_fits_one_chip(one_chip, name, params, scopes):
     """The whole generated program, superstep counters included: it fits
     one v5e, its HLO names the loop and relax scopes, and it holds no host
     callback."""
-    args = {k: _shape(one_chip, (), dt) for k, dt in params.items()}
-    compiled = compile_bundled(name).fn.lower(_graph(one_chip), **args).compile()
+    compiled = _compile_program(one_chip, name, params)
     text = compiled.as_text()
     assert _device_bytes(compiled) < HBM_BYTES
     assert all(s in text for s in scopes), scopes
     assert "callback" not in text.lower()
+
+
+def _computations(hlo: str) -> dict:
+    """Computation name -> its instruction lines, from HLO text."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(", line)
+        if head and line.rstrip().endswith("{"):
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _reached(comps: dict, root: str) -> set:
+    """`root` and every computation it calls, however deep."""
+    seen, todo = set(), [root]
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo.extend(n for line in comps[c]
+                        for n in re.findall(r"%([\w.\-]+)", line)
+                        if n in comps)
+    return seen
+
+
+def _edge_gathers(comps: dict, root: str) -> int:
+    """E-sized gathers in `root` and what it calls."""
+    gather = re.compile(rf"= \w+\[{E}\]\S* gather\(")
+    return sum(bool(gather.search(line)) for c in _reached(comps, root)
+               for line in comps[c])
+
+
+def _callee(lines, op: str, attr: str) -> list:
+    """The computations `attr` names on the `op` instructions of `lines`."""
+    return [n for line in lines if f" {op}(" in line
+            for n in re.findall(r"%([\w.\-]+)",
+                                re.search(rf"{attr}=(\{{[^}}]*\}}|\S+)",
+                                          line).group(1))]
+
+
+def test_one_edge_gather_per_sweep(one_chip):
+    """Each neighbor loop gathers one vertex array over the edges: the
+    PageRank loop body divides by the degree before its gather, and each
+    branch of the SSSP relax folds the frontier into the distances before
+    its gather."""
+    hlo = {name: _compile_program(one_chip, name, params).as_text()
+           for name, params, _ in PROGRAMS}
+    pr = _computations(hlo["pr"])
+    (body,) = [b for c in pr.values() for b in _callee(c, "while", "body")]
+    assert _edge_gathers(pr, body) == 1
+
+    sssp = _computations(hlo["sssp"])
+    (body,) = [b for c in sssp.values()
+               for b in _callee(c, "while", "body")]
+    branches = _callee(sssp[body], "conditional", "branch_computations")
+    assert len(branches) == 2
+    assert [_edge_gathers(sssp, b) for b in branches] == [1, 1]
 
 
 @pytest.mark.parametrize("lanes,error", [(None, MOSAIC_ERRORS[0]),
