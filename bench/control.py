@@ -10,6 +10,7 @@ line per seed.
     python3 bench/control.py --workload <cell> --control bfloat16 --seeds 11 12 13
 """
 import argparse
+import contextlib
 import json
 import os
 import sys
@@ -20,9 +21,12 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import harness  # noqa: E402
 
 
-def put_control_in_place(workload: str, seed: int, control: str,
-                         root: str = harness.ROOT) -> None:
-    """Make `harness.run_cell` run the control instead of the program."""
+@contextlib.contextmanager
+def control_in_place(workload: str, control: str, root: str = harness.ROOT):
+    """Make `harness.run_cell` run the control instead of the program while
+    the block runs."""
+    saved = {step: getattr(harness, step)
+             for step in ("build_graph", "prepare_graph", "bind_program")}
     bench = harness.load_json(os.path.join(root, "BENCHMARK.json"))
     cell = harness.find_cell(bench, workload)
     traffic = harness.load_json(os.path.join(harness.BENCH_DIR, "traffic",
@@ -30,8 +34,14 @@ def put_control_in_place(workload: str, seed: int, control: str,
     program = harness.load_module("programs", traffic["program"])
     reference = harness.load_module("references", traffic["program"])
     harness.build_graph = lambda repro, edges: edges
-    harness.bind_program = lambda repro, prog, edges: program.CONTROLS[control](
+    harness.prepare_graph = lambda repro, prog, edges, mesh=None: None
+    harness.bind_program = lambda repro, prog, edges, mesh=None: program.CONTROLS[control](
         reference, edges, traffic)
+    try:
+        yield
+    finally:
+        for step, fn in saved.items():
+            setattr(harness, step, fn)
 
 
 def main(argv=None, root: str = harness.ROOT) -> int:
@@ -40,18 +50,15 @@ def main(argv=None, root: str = harness.ROOT) -> int:
     ap.add_argument("--control", default="bfloat16")
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     args = ap.parse_args(argv)
-    build, bind = harness.build_graph, harness.bind_program
     for seed in args.seeds:
-        put_control_in_place(args.workload, seed, args.control, root)
         try:
-            line = harness.run_cell(argparse.Namespace(
-                workload=args.workload, seed=seed, seconds=0.0, trace=0),
-                root=root, t_start=time.perf_counter())
+            with control_in_place(args.workload, args.control, root):
+                line = harness.run_cell(argparse.Namespace(
+                    workload=args.workload, seed=seed, seconds=0.0, trace=0),
+                    root=root, t_start=time.perf_counter())
         except harness.NoDevice as e:
             print(f"control: {e}", file=sys.stderr)
             return 2
-        finally:
-            harness.build_graph, harness.bind_program = build, bind
         print(json.dumps({"control": args.control, "workload": args.workload, "seed": seed,
                           "correct": line["correct"], "checks": line["checks"],
                           "info": line["info"]}), flush=True)

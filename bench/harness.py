@@ -4,7 +4,8 @@ Everything that belongs to one configuration, traffic mix, program or
 metric sits in a file of its own, found by name:
 
     BENCHMARK.json               cells, metrics, bounds
-    bench/configs/<config>.json  the deployment: generator and its parameters
+    bench/configs/<config>.json  the deployment: generator and its parameters,
+                                 and the backend the graph lies on
     bench/graphs/<generator>.py  `generate(params, seed)` -> edge list
     bench/traffic/<traffic>.json the mix: program, parameters, loop, limits
     bench/programs/<program>.py  drives one bundled program: inputs, warm-up,
@@ -14,8 +15,14 @@ metric sits in a file of its own, found by name:
     bench/metrics/<metric>.py    `read(ctx)` -> one per-layer number or None
     bench/peaks.json             device peaks keyed by `device_kind`
 
+A configuration's `backend` is `local` (the default: the whole graph on
+one chip) or `distributed` (the graph partitioned 1-D over a mesh of the
+cell's chips, one `shard_map` program exchanging vertex state by
+collectives).
+
 A run: find the chip; make the edge list from the seed; build the graph
-and compile, bind and warm up the program (all of it `setup_s`); measure
+and its derived views (the 1-D partition on `distributed`), compile, bind
+and warm up the program (all of it `setup_s`); measure
 the window; optionally read the profiler's trace of the window; free the
 device; compare every output of the window with the reference.
 """
@@ -138,10 +145,15 @@ def build_graph(repro, edges: dict):
                                   drop_self_loops=edges["drop_self_loops"])
 
 
-def bind_program(repro, prog, g):
+def prepare_graph(repro, prog, g, mesh=None):
+    """The graph's derived views for `prog` (host views): on the
+    distributed backend, the 1-D partition placed over `mesh`."""
+    repro.core.prepare(g, program=prog, mesh=mesh)
+
+
+def bind_program(repro, prog, g, mesh=None):
     """The program's entry, bound to the graph: `bound(**params)`."""
-    repro.core.prepare(g, program=prog)
-    return prog.bind(g)
+    return prog.bind(g, mesh=mesh)
 
 
 # -------------------------------------------------------------------------------
@@ -162,19 +174,39 @@ def device_info(devices) -> dict:
             "count": len(devices)}
 
 
+def make_mesh(backend: str, devices):
+    """The 1-D mesh over exactly the cell's devices, or None on a backend
+    that runs on one device."""
+    if backend != "distributed":
+        return None
+    from repro.core import dist, runtime_dist
+    return dist.make_mesh((len(devices),), (runtime_dist.AXIS,), devices=devices)
+
+
 def compiled_text(bound, params: dict) -> str:
     """The optimised HLO of the program the window ran, which names the
-    fused computations the trace's ops call (from the persistent cache)."""
-    fn = getattr(getattr(bound, "program", None), "fn", None)
+    fused computations the trace's ops call (from the persistent cache):
+    the bound program's own `lower` where it has one; else, on the
+    distributed backend, its `shard_map` runner lowered on the partition
+    it holds; else the jitted function lowered on the graph."""
+    if hasattr(bound, "lower"):
+        return bound.lower(**params).compile().as_text()
+    prog = getattr(bound, "program", None)
+    if getattr(prog, "backend", None) == "distributed":
+        from repro.core import dist
+        names = tuple(n for n, v in params.items() if v is not None)
+        fn = dist._runner(prog, bound._gd, bound.mesh, names, prog.dist_meta or {})
+        return fn.lower(bound._gd, *(params[n] for n in names)).compile().as_text()
+    fn = getattr(prog, "fn", None)
     if not hasattr(fn, "lower"):
         return ""
     return fn.lower(bound.graph, **params).compile().as_text()
 
 
-def memory_peak_bytes(devices):
+def memory_peaks(devices) -> list:
+    """`peak_bytes_in_use` of each device that reports it."""
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in devices]
-    peaks = [p for p in peaks if p is not None]
-    return max(peaks) if peaks else None
+    return [p for p in peaks if p is not None]
 
 
 def run_cell(args, *, root: str, t_start: float) -> dict:
@@ -183,6 +215,7 @@ def run_cell(args, *, root: str, t_start: float) -> dict:
     cell = find_cell(bench, args.workload)
     config_entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     config = load_json(os.path.join(root, config_entry["file"]))
+    backend = config.get("backend", "local")
     traffic = load_json(os.path.join(BENCH_DIR, "traffic", f"{cell['traffic']}.json"))
 
     spans = Spans()
@@ -202,13 +235,15 @@ def run_cell(args, *, root: str, t_start: float) -> dict:
     with spans("generate"):
         edges = generator.generate(config["params"], args.seed)
     plan = program.plan(edges, traffic, args.seed)
+    mesh = make_mesh(backend, devices)
     with spans("compile"):
-        prog = repro.core.compile_bundled(program.BUNDLED)
+        prog = repro.core.compile_bundled(program.BUNDLED, backend=backend)
     with spans("graph_build"):
         g = build_graph(repro, edges)
+        prepare_graph(repro, prog, g, mesh)
         jax.block_until_ready(g)
     with spans("compile"):
-        bound = bind_program(repro, prog, g)
+        bound = bind_program(repro, prog, g, mesh)
         jax.block_until_ready(bound(**plan["warmup"]))
     setup_s = time.perf_counter() - t_start
 
@@ -227,7 +262,8 @@ def run_cell(args, *, root: str, t_start: float) -> dict:
         hlo = compiled_text(bound, plan["inputs"][0])
         trace = trace_reader.read_dir(trace_dir, [d.id for d in devices], hlo)
         shutil.rmtree(trace_dir, ignore_errors=True)
-    device["memory_peak_bytes"] = memory_peak_bytes(devices)
+    chip_peaks = memory_peaks(devices)
+    device["memory_peak_bytes"] = max(chip_peaks) if chip_peaks else None
 
     # the outputs to host, the program's state freed, then the reference
     outputs = [program.output(o) for o in window.pop("outputs")]
@@ -260,7 +296,8 @@ def run_cell(args, *, root: str, t_start: float) -> dict:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         device["busy_s"] = trace["busy_s"]
         device["window_s"] = trace["window_s"]
-        window["info"].update(class_s=trace["class_s"], control_ops=trace["control_ops"])
+        window["info"].update(class_s=trace["class_s"], control_ops=trace["control_ops"],
+                              collective_exposed_s=trace["collective_exposed_s"])
     else:
         values = dict(window["metrics"], setup_s=setup_s)
         metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
@@ -274,6 +311,7 @@ def run_cell(args, *, root: str, t_start: float) -> dict:
     line["info"] = {"workload": cell["name"], "seed": args.seed,
                     "setup_s": setup_s, "spans_s": spans.seconds,
                     "window_compiles": compiles.count, "reference_s": reference_s,
+                    "memory_peak_bytes_per_chip": chip_peaks,
                     **window["info"], **check["info"]}
     line["checks"] = checks
     return line

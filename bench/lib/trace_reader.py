@@ -11,14 +11,21 @@ On a TPU each op event is named by its HLO instruction as text:
 The reduction reads the instruction's opcode and, for a fusion, the fused
 computation it calls; `op_class` looks those computations up in the
 compiled module's text, so a fusion whose body holds a scatter counts as
-scatter and one whose body holds a gather as gather.
+scatter and one whose body holds a gather as gather. A collective (an
+all-gather, all-reduce, reduce-scatter, all-to-all or collective-permute,
+its `-start`/`-done` halves, or a fusion or async op whose computation
+holds one) counts as collective, whatever else it holds.
 
 - window: the host span `bench.window` (the benchmark's own annotation);
 - busy: the union of the device's op intervals inside the window, leaving
   out the control ops (`while`, `conditional`, `call`) that only contain
   other ops; those are counted (a `conditional` per SSSP superstep);
 - idle gaps: the stretches of the window with no op on the device, each
-  named by the innermost host event that covers its middle.
+  named by the innermost host event that covers its middle;
+- exposed collective time: the part of the collective ops' intervals that
+  no other op on the same device covers.
+
+Every time is a mean over the device planes read (`chips`).
 """
 from __future__ import annotations
 
@@ -29,6 +36,8 @@ import re
 WINDOW = "bench.window"
 OPS_LINE = "XLA Ops"
 CONTAINERS = ("while", "conditional", "call")
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+               "collective-permute")
 
 _INSTR = re.compile(r"^%?([\w.\-]+)\s*=\s*.*?\s([a-z][a-z0-9\-]*)\(")
 _CALLS = re.compile(r"calls=%?([\w.\-]+)")
@@ -75,8 +84,14 @@ def computation_opcodes(hlo_text: str) -> dict:
     return {name: close(name, {name}) for name in body}
 
 
+def is_collective(opcode: str) -> bool:
+    return opcode.removesuffix("-start").removesuffix("-done") in COLLECTIVES
+
+
 def op_class(opcode: str, called: str, comps: dict) -> str:
     ops = {opcode} | comps.get(called, set())
+    if any(is_collective(op) for op in ops):
+        return "collective"
     for cls in ("scatter", "gather"):
         if cls in ops:
             return cls
@@ -144,9 +159,9 @@ def reduce(events: dict, hlo_text: str = "", top: int = 10) -> dict:
         raise ValueError(f"no {WINDOW!r} span in the trace")
     lo, hi = spans[0]
     comps = computation_opcodes(hlo_text)
-    busy_ns, op_ns, class_ns, gap_ns, loops = 0.0, {}, {}, {}, {}
+    busy_ns, exposed_ns, op_ns, class_ns, gap_ns, loops = 0.0, 0.0, {}, {}, {}, {}
     for ops in events["device"].values():
-        leaf = []
+        leaf, compute = [], []
         for instr, opcode, called, s, d in ops:
             s, e = max(s, lo), min(s + d, hi)
             if e <= s:
@@ -156,10 +171,14 @@ def reduce(events: dict, hlo_text: str = "", top: int = 10) -> dict:
                 continue
             leaf.append((s, e))
             cls = op_class(opcode, called, comps)
+            if cls != "collective":
+                compute.append((s, e))
             key = f"{instr} ({cls})"
             op_ns[key] = op_ns.get(key, 0.0) + (e - s)
             class_ns[cls] = class_ns.get(cls, 0.0) + (e - s)
-        busy_ns += union_length(leaf)
+        busy = union_length(leaf)
+        busy_ns += busy
+        exposed_ns += busy - union_length(compute)
         for s, e in gaps(leaf, lo, hi):
             name = host_name_at(host, (s + e) / 2)
             gap_ns[name] = gap_ns.get(name, 0.0) + (e - s)
@@ -167,7 +186,8 @@ def reduce(events: dict, hlo_text: str = "", top: int = 10) -> dict:
 
     def ranked(d):
         return [[k, v / chips / 1e9] for k, v in sorted(d.items(), key=lambda kv: -kv[1])[:top]]
-    return {"window_s": (hi - lo) / 1e9, "busy_s": busy_ns / chips / 1e9,
+    return {"window_s": (hi - lo) / 1e9, "busy_s": busy_ns / chips / 1e9, "chips": chips,
+            "collective_exposed_s": exposed_ns / chips / 1e9,
             "class_s": {k: v / chips / 1e9 for k, v in class_ns.items()},
             "control_ops": {k: v / chips for k, v in loops.items()},
             "breakdown": {"device_ops": ranked(op_ns), "idle_gaps": ranked(gap_ns)}}

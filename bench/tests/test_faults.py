@@ -11,8 +11,9 @@ import pytest
 import control
 import edgelist
 import harness
+from conftest import DIST_CELL
 
-CELLS = ["g500-s21.pr", "g500-s21.sssp"]
+CELLS = ["g500-s21.pr", "g500-s21.sssp", DIST_CELL]
 SSSP_CELLS = [c for c in CELLS if c.endswith(".sssp")]
 
 
@@ -20,8 +21,8 @@ def plant(monkeypatch, fault):
     """Run `fault(bound, params)` in place of each call of the program."""
     real = harness.bind_program
 
-    def bind(repro, prog, g):
-        bound = real(repro, prog, g)
+    def bind(repro, prog, g, mesh=None):
+        bound = real(repro, prog, g, mesh)
         return lambda **params: fault(bound, params)
     monkeypatch.setattr(harness, "bind_program", bind)
 
@@ -103,16 +104,12 @@ def test_one_edge_dropped_from_the_csr_is_not_correct(drive, monkeypatch, cell):
 
 
 def run_control(drive, tiny_root, cell, name):
-    seed = 2**31 + 11
-    build, bind = harness.build_graph, harness.bind_program
-    try:
-        control.put_control_in_place(cell, seed, name, tiny_root)
-        return drive(cell, seed=seed)
-    finally:
-        harness.build_graph, harness.bind_program = build, bind
+    with control.control_in_place(cell, name, tiny_root):
+        return drive(cell, seed=2**31 + 11)
 
 
 @pytest.mark.parametrize("cell,name", [("g500-s21.pr", "bfloat16"),
+                                       (DIST_CELL, "bfloat16"),
                                        ("g500-s21.sssp", "bfloat16"),
                                        ("g500-s21.sssp", "int16"),
                                        ("g500-s21.sssp", "stopped_short")])

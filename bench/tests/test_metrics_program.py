@@ -130,7 +130,8 @@ def test_traced_run_reports_the_program_metrics(drive, tiny_root, monkeypatch, c
     that reads the program appears in the result line."""
     import trace_reader
     monkeypatch.setattr(trace_reader, "read_dir", lambda *a: {
-        "window_s": 1.0, "busy_s": 0.5, "class_s": {"gather": 0.3, "scatter": 0.2},
+        "window_s": 1.0, "busy_s": 0.5, "chips": 1, "collective_exposed_s": 0.0,
+        "class_s": {"gather": 0.3, "scatter": 0.2},
         "control_ops": {}, "breakdown": {"device_ops": [], "idle_gaps": []}})
     fresh_process()
     args = argparse.Namespace(workload=cell, seed=2**31 + 7, seconds=0.05, trace=1)

@@ -80,12 +80,14 @@ def test_reduce_without_window_fails():
         tr.reduce({"host": [], "device": {}})
 
 
-@pytest.mark.parametrize("name,scatter_ops,gather_ops,loops", [
-    ("pr", ["fusion.15"], ["fusion.13", "fusion.14"], {"while": 2}),
+@pytest.mark.parametrize("name,scatter_ops,gather_ops,loops,busy_s,class_s", [
+    ("pr", ["fusion.15"], ["fusion.13", "fusion.14"], {"while": 2}, 0.671990397,
+     {"other": 0.000327025, "gather": 0.417056676, "scatter": 0.254606696}),
     ("sssp", ["fusion.4", "fusion.5"], ["fusion", "fusion.1", "fusion.2", "fusion.3"],
-     {"while": 2, "conditional": 20}),
+     {"while": 2, "conditional": 20}, 0.797677527,
+     {"other": 0.000485055, "gather": 0.499562929, "scatter": 0.297629543}),
 ])
-def test_recorded_tpu_trace(name, scatter_ops, gather_ops, loops):
+def test_recorded_tpu_trace(name, scatter_ops, gather_ops, loops, busy_s, class_s):
     """Read by hand: in pr.sp the segment sum is a kCustom fusion around a
     scatter, the two edge-sized gathers (rank and out-degree of each
     in-edge's source) are fusions around a gather; sssp.sp adds the push
@@ -106,3 +108,58 @@ def test_recorded_tpu_trace(name, scatter_ops, gather_ops, loops):
     assert 0.98 < r["busy_s"] / r["window_s"] <= 1.0
     assert sum(r["class_s"].values()) >= r["busy_s"] * 0.999
     assert r["control_ops"] == loops
+    # one chip, no collective: the numbers read before collectives had a class
+    assert r["chips"] == 1 and r["collective_exposed_s"] == 0
+    assert r["busy_s"] == pytest.approx(busy_s, rel=1e-12)
+    assert r["class_s"] == pytest.approx(class_s, rel=1e-12)
+
+
+def test_collectives_class_before_gather_and_scatter():
+    hlo = "\n".join([
+        "%ag (p: f32[4]) -> f32[16] {",
+        "  ROOT %all-gather.1 = f32[16]{0} all-gather(%p), dimensions={0}",
+        "}",
+        "%mixed (p: f32[16], i: s32[8]) -> f32[8] {",
+        "  %f = f32[16]{0} fusion(%p), kind=kLoop, calls=%ag",
+        "  ROOT %gather.2 = f32[8]{0} gather(%f, %i), slice_sizes={1}",
+        "}",
+    ])
+    comps = tr.computation_opcodes(hlo)
+    for opcode in ("all-gather", "all-gather-start", "all-gather-done", "all-reduce",
+                   "all-reduce-start", "all-reduce-done", "reduce-scatter", "all-to-all",
+                   "collective-permute", "collective-permute-start",
+                   "collective-permute-done"):
+        assert tr.op_class(opcode, "", comps) == "collective", opcode
+    assert tr.op_class("fusion", "ag", comps) == "collective"
+    assert tr.op_class("fusion", "mixed", comps) == "collective"
+    assert tr.op_class("all-gathers", "", comps) == "other"
+
+
+def test_exposed_collective_time():
+    """Two devices. On the first, an async all-gather's halves and a fusion
+    around an all-gather, partly covered by compute; on the second, a
+    collective no compute covers. Times are means over the two."""
+    hlo = "%ag (p: f32[4]) -> f32[16] {\n  ROOT %all-gather.1 = f32[16]{0} all-gather(%p)\n}"
+    events = {
+        "host": [["bench.window", 0, 100]],
+        "device": {
+            "/device:TPU:0": [
+                ["all-gather-start.1", "all-gather-start", "", 0, 10],
+                ["add.1", "add", "", 5, 30],                      # covers [5, 35]
+                ["all-gather-done.1", "all-gather-done", "", 30, 10],   # exposed [35, 40]
+                ["fusion.2", "fusion", "ag", 50, 20],             # exposed [50, 60]
+                ["scatter.3", "scatter", "", 60, 20],
+            ],
+            "/device:TPU:1": [
+                ["all-reduce.4", "all-reduce", "", 0, 40],        # all exposed
+                ["gather.5", "gather", "", 40, 10],
+            ],
+        },
+    }
+    r = tr.reduce(events, hlo)
+    assert r["chips"] == 2
+    assert r["collective_exposed_s"] == pytest.approx((5 + 5 + 10 + 40) / 2 * 1e-9)
+    assert r["class_s"]["collective"] == pytest.approx((10 + 10 + 20 + 40) / 2 * 1e-9)
+    assert r["class_s"]["other"] == pytest.approx(30 / 2 * 1e-9)
+    assert r["busy_s"] == pytest.approx((70 + 50) / 2 * 1e-9)
+    assert dict(r["breakdown"]["device_ops"])["fusion.2 (collective)"] == pytest.approx(10e-9)
