@@ -9,10 +9,10 @@ from .api import (BoundProgram, CompiledProgram, bind_cache_clear,
                   bind_cache_size, bundled_programs, compile_bundled,
                   compile_cache_clear, compile_cache_size, compile_program,
                   load_program_source)
-from .context import GraphContext, get_context, prepare
+from .context import GraphContext, get_context, on_device, prepare
 
 __all__ = ["BoundProgram", "CompiledProgram", "DEFAULT_SCHEDULE",
            "GraphContext", "Schedule", "bind_cache_clear", "bind_cache_size",
            "bundled_programs", "compile_bundled", "compile_cache_clear",
            "compile_cache_size", "compile_program", "get_context",
-           "load_program_source", "prepare"]
+           "load_program_source", "on_device", "prepare"]

@@ -32,7 +32,7 @@ from ..trace import device_counters, span
 from . import runtime as rt
 from .analysis import (DiagnosticError, check_schedule, entry_error,
                        program_analysis, split)
-from .context import get_context
+from .context import get_context, on_device
 from .lowering import lower
 from .parser import parse
 
@@ -135,16 +135,17 @@ class BoundProgram:
         ctx = get_context(graph)
         if program.backend == "distributed":
             from . import dist
+            from . import runtime_dist as rtd
             self.mesh = mesh if mesh is not None else dist.make_mesh_1d()
-            meta = program.dist_meta or {}
             self._gd = ctx.dist_arrays(self.mesh,
-                                       ell=meta.get("needs_ell", False))
+                                       **rtd.view_flags(program.dist_meta))
         else:
             if mesh is not None:
                 raise ValueError(
                     "mesh= applies to the distributed backend only (this "
                     f"program's backend is {program.backend!r})")
             self.mesh = None
+            on_device(graph)      # warm the one device copy every call runs on
             if program.backend == "pallas":
                 ctx.sliced_ell(program.schedule, reverse=True)
             elif program.backend == "local" and ", _dell" in program.source:
@@ -350,30 +351,35 @@ def compile_program(source: str, backend: str = "local",
 
     # CSRGraph is a registered pytree with static num_nodes/num_edges metadata,
     # so the graph argument is dynamic (arrays) + static (sizes) automatically.
+    # The local and pallas entries take the graph as built on the host and
+    # run on its one device copy (`on_device`), owned by its GraphContext.
     def _wrap(raw_fn):
+        if backend == "distributed":
+            return raw_fn
+        jitted = jax.jit(raw_fn) if jit else raw_fn
         if backend == "pallas":
-            jitted = jax.jit(raw_fn) if jit else raw_fn
-
             def fn(g, *, _jitted=jitted, _sched=sched, **kw):
                 # degree-bucketed reverse (in-edge) view, owned by the
                 # graph's shared GraphContext — built once per (graph,
                 # layout), shared with every other program compiled under
                 # the same layout.
                 ell = get_context(g).sliced_ell(_sched, reverse=True)
-                return _jitted(g, ell, **kw)
+                return _jitted(on_device(g), ell, **kw)
             return fn
-        if backend == "local" and \
-                f"def {irfn.name}({irfn.graph_param}, _dell" in body:
+        if f"def {irfn.name}({irfn.graph_param}, _dell" in body:
             # delta-stepping program: the generated functions take the
             # padded forward-ELL view the compact bucket relax gathers
             # frontier out-rows from (None on hub-heavy graphs → dense
             # fallback)
-            jitted = jax.jit(raw_fn) if jit else raw_fn
-
             def fn(g, *, _jitted=jitted, **kw):
-                return _jitted(g, get_context(g).delta_ell(), **kw)
+                return _jitted(on_device(g), get_context(g).delta_ell(), **kw)
             return fn
-        return jax.jit(raw_fn) if jit and backend == "local" else raw_fn
+
+        def fn(g, *, _jitted=jitted, **kw):
+            return _jitted(on_device(g), **kw)
+        if jit:
+            fn.lower = jitted.lower
+        return fn
 
     fn = _wrap(raw)
     refresh_fn = _wrap(raw_refresh) if raw_refresh is not None else None

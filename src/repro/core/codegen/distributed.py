@@ -42,7 +42,9 @@ byte-identical source):
 
 Every generated program additionally returns `_gather_elems`, the number
 of property-exchange elements its collectives actually moved — the
-communication-volume measurement `benchmarks/bench_dist.py` reports.
+communication-volume measurement `benchmarks/bench_dist.py` reports — and
+a program with a top-level loop `_supersteps`, the loop bodies it ran, as
+on the local backend.
 
 The generated function body runs per device; `repro.core.dist.run()` wraps
 it in `jax.shard_map` over the mesh's 'data' axis.
@@ -55,7 +57,7 @@ from .. import ir as I
 from ..ir import read_props
 from .base import (BFSCtx, CodegenError, EdgeCtx, ExprEmitter, HostCtx,
                    VertexCtx, only_reads_side, relax_candidate)
-from .local_jax import LocalCodegen
+from .local_jax import LocalCodegen, has_refresh_variant
 
 # Ablation switch for the loop-invariant gather hoist: properties a BSP
 # loop body reads but never writes are gathered once before the loop
@@ -67,7 +69,7 @@ HOIST_INVARIANT = True
 
 _PARTITIONED_KEYS = ["esrc", "edst", "ew", "evalid", "esrc_local",
                      "idst", "isrc", "iw", "ivalid", "idst_local", "own_ids"]
-_REPLICATED_KEYS = ["out_degree_rep", "in_degree_rep", "edge_key_rep", "n_true_rep"]
+_REPLICATED_KEYS = ["out_degree_rep", "in_degree_rep", "n_true_rep"]
 
 
 class DistExprEmitter(ExprEmitter):
@@ -130,13 +132,15 @@ class DistCodegen(LocalCodegen):
     # sequential per-source fallback: fused lanes would need shard-uniform
     # per-lane trip counts threaded through every BSP superstep
     supports_batched_scalar_loops = False
-    # the BSP loops return `_gather_elems`, not the local superstep counters
-    superstep_counters = False
+    # top-level BSP loops count their supersteps; the relax counters of the
+    # local backend have no distributed twin (see `_count_relax`)
+    counters = (("_supersteps", "jnp.int32"),)
 
     def __init__(self, irfn: I.IRFunction, schedule=None):
         super().__init__(irfn, schedule=schedule)
         self.ex = DistExprEmitter(irfn, graph_var=irfn.graph_param)
         self.needs_ell = False
+        self.needs_edge_key = False
         # stack of property groups whose `{p}_full` views are carried
         # through the enclosing BSP loop (compact/auto exchange policies)
         self._full_stack = []
@@ -183,13 +187,21 @@ class DistCodegen(LocalCodegen):
                         em.w(f"{p.name} = rt.init_prop(B, {self.jdt(p.dtype)})")
                 elif p.kind == "scalar":
                     self.dtypes[p.name] = p.dtype
+            if has_refresh_variant(f):
+                for c, dt in self.counters:
+                    self.declare(c, "int32")
+                    em.w(f"{c} = {dt}(0)")
             for s in f.body:
+                self._top = s
                 self.stmt(s, HostCtx())
             rets = ", ".join(f"'{v}': {v}" for v in self.declared)
             em.w(f"return {{{rets}}}")
         return em.source()
 
     # ------------------------------------------------------------------ helpers
+    def _count_relax(self, frontier, pushed, swept: str):
+        """No relax counters here: a shard sweeps only its own edges."""
+
     def _edge_term(self, expr, ctx) -> str:
         # a term's neighbor operands read the exchanged `{p}_full` buffers
         # by global id; the shard's own [B] vertex block cannot stand in
@@ -787,10 +799,11 @@ class DistCodegen(LocalCodegen):
             raise CodegenError("wedge body must be a count reduction")
         if self.batch is not None:
             raise CodegenError("wedge pattern inside a batched source loop")
-        self.needs_ell = True
+        self.needs_ell = self.needs_edge_key = True
         dt = self.dtype_of(red.name)
         acc = (f"{red.name} + rtd.wedge_count_1d(ell_cols, own_ids, "
-               f"edge_key_rep, n_true) * ({self.ex.expr(red.expr, HostCtx())})")
+               f"{self.f.graph_param}['edge_key_rep'], n_true) * "
+               f"({self.ex.expr(red.expr, HostCtx())})")
         self.em.w(f"{red.name} = jnp.asarray({acc}, {self.jdt(dt)})" if dt else
                   f"{red.name} = {acc}")
         return True
@@ -809,5 +822,6 @@ def generate_distributed(irfn: I.IRFunction, schedule=None, **opts):
         "out_props": [v for v in cg.declared if v in irfn.node_props],
         "out_scalars": [v for v in cg.declared if v not in irfn.node_props],
         "needs_ell": cg.needs_ell,
+        "needs_edge_key": cg.needs_edge_key,
     }
     return body, {"rtd": rtd, "__dist_meta__": meta}

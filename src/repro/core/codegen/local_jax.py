@@ -52,8 +52,8 @@ class LocalCodegen:
     # sequential per-source fallback instead (its BSP supersteps would need
     # shard-uniform trip counts per lane)
     supports_batched_scalar_loops = True
-    # top-level loops carry and return the `COUNTERS`
-    superstep_counters = True
+    # device counters the top-level loops carry and return
+    counters = COUNTERS
 
     def __init__(self, irfn: I.IRFunction, schedule: Optional[Schedule] = None,
                  batch_sources: Optional[int] = None):
@@ -238,9 +238,9 @@ class LocalCodegen:
                         em.w(f"{p.name} = rt.init_prop(N, {self.jdt(p.dtype)!s})")
                 elif p.kind == "scalar":
                     self.dtypes[p.name] = p.dtype
-            counters = self.superstep_counters and has_refresh_variant(f)
+            counters = has_refresh_variant(f)
             if counters:
-                for c, dt in COUNTERS:
+                for c, dt in self.counters:
                     em.w(f"{c} = {dt}(0)")
             warm_pending = self.refresh_variant
             for s in f.body:
@@ -250,17 +250,17 @@ class LocalCodegen:
                     warm_pending = False
                 self._top = s
                 self.stmt(s, HostCtx())
-            rets = self.declared + ([c for c, _ in COUNTERS] if counters else [])
+            rets = self.declared + ([c for c, _ in self.counters] if counters else [])
             rets = ", ".join(f"'{v}': {v}" for v in rets)
             em.w(f"return {{{rets}}}")
         return em.source()
 
     def _counted_carry(self, s: I.IRStmt, carry: List[str]) -> bool:
-        """True when loop `s` is top-level and so carries the COUNTERS
+        """True when loop `s` is top-level and so carries the counters
         (appended to `carry`)."""
-        counted = self.superstep_counters and s is self._top
+        counted = s is self._top
         if counted:
-            carry.extend(c for c, _ in COUNTERS)
+            carry.extend(c for c, _ in self.counters)
         return counted
 
     @contextlib.contextmanager

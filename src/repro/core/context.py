@@ -10,8 +10,15 @@ for a graph lives in ONE `GraphContext`, found through a weakref-keyed
 module registry:
 
     ctx = get_context(g)                 # registered on first touch
+    dg  = ctx.device_graph()             # the whole CSR on one device, once
     ell = ctx.sliced_ell(schedule)       # built once per (layout, reverse)
     gd  = ctx.dist_arrays(mesh)          # built once per partitioning
+
+`from_edges` builds the graph on the host (numpy arrays); every device
+copy is a view here. The local and pallas programs run on
+`device_graph()`, the distributed one on `dist_arrays(mesh)`, which
+places each shard straight from the host arrays onto its own device, so
+a distributed graph never has a whole copy on any one device.
 
 Entries hold a WEAK reference to the graph: `id(g)` alone is unsafe (ids
 are reused after GC, so a dead graph could alias a new one's views) and a
@@ -38,6 +45,8 @@ import hashlib
 import weakref
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..graph.csr import (CSRGraph, pad_nodes, resolve_schedule, to_ell,
@@ -116,6 +125,17 @@ class GraphContext:
         return freed
 
     # ---- the derived structures ------------------------------------------
+    def device_graph(self) -> CSRGraph:
+        """The graph with every array on the default device: the one
+        device copy the local and pallas programs run on, made once."""
+        def build(g):
+            with span("graph.to_device"):
+                return dataclasses.replace(g, **{
+                    f.name: jnp.asarray(getattr(g, f.name))
+                    for f in dataclasses.fields(g)
+                    if not f.metadata.get("static")})
+        return self.view(("device",), build)
+
     def sliced_ell(self, schedule: Optional[Schedule] = None, *,
                    reverse: bool = True):
         """Degree-bucketed sliced-ELL view (+ COO hub tail). `reverse=True`
@@ -152,13 +172,16 @@ class GraphContext:
         return self.view(("padded", int(multiple)),
                          lambda g: pad_nodes(g, multiple))
 
-    def dist_arrays(self, mesh, *, ell: bool = False) -> dict:
+    def dist_arrays(self, mesh, *, ell: bool = False,
+                    edge_key: bool = False) -> dict:
         """1-D block-partitioned arrays for the distributed backend, placed
-        on `mesh` (one view per mesh; key[1] is its shard count)."""
+        on `mesh` (one view per mesh; key[1] is its shard count), with the
+        replicated edge key only for a program that reads it."""
         from . import runtime_dist as rtd
-        key = ("dist_1d", int(mesh.shape[rtd.AXIS]), bool(ell), mesh)
+        key = ("dist_1d", int(mesh.shape[rtd.AXIS]), bool(ell),
+               bool(edge_key), mesh)
         return self.view(key, lambda g: rtd.prepare_graph_1d(
-            g, mesh, ell=ell))
+            g, mesh, ell=ell, edge_key=edge_key))
 
     def fingerprint(self) -> str:
         """Stable content digest of the graph (structure + weights).
@@ -322,18 +345,20 @@ def clear() -> None:
 def prepare(g: CSRGraph, schedule: Optional[Schedule] = None, *,
             backend: str = "pallas", mesh=None, program=None) -> GraphContext:
     """Explicit warm-up: build the derived structures `backend` needs so the
-    first query served against `g` pays no host-side view construction.
+    first query served against `g` pays no view construction or transfer.
 
-    * ``pallas`` — the reverse sliced-ELL view for `schedule`'s layout;
+    * ``local`` — the graph's one device copy (`device_graph()`);
+    * ``pallas`` — that copy and the reverse sliced-ELL view for
+      `schedule`'s layout;
     * ``distributed`` — the 1-D partition for `mesh` (default: one shard
-      per local device); pass `program=` so programs whose generated body
-      needs the replicated ELL view (`dist_meta["needs_ell"]`, e.g. TC)
-      warm the exact partition `bind` will request;
-    * ``local`` — nothing derived (the CSR arrays ARE the layout); the
-      context is still registered so `bind` is uniform.
+      per local device), and no whole copy on any device; pass `program=`
+      so the views its generated body reads (`dist_meta`: the replicated
+      ELL view and edge key of TC) are the ones `bind` will request.
 
     `program=` also supplies the schedule/backend defaults:
     `prepare(g, program=prog)` warms precisely what `prog.bind(g)` needs.
+    It waits until the views are on their devices, so their transfer is
+    part of the warm-up and not of the first query.
 
     Returns the graph's `GraphContext` (the same object every consumer of
     `g` sees). Idempotent and cheap when already warm."""
@@ -344,19 +369,32 @@ def prepare(g: CSRGraph, schedule: Optional[Schedule] = None, *,
     sched = resolve_schedule(schedule)
     with span("prepare"):
         ctx = get_context(g)
-        if backend == "pallas":
-            ctx.sliced_ell(sched, reverse=True)
+        if backend == "local":
+            views = ctx.device_graph()
+        elif backend == "pallas":
+            views = (ctx.device_graph(), ctx.sliced_ell(sched, reverse=True))
         elif backend == "distributed":
+            from . import runtime_dist as rtd
             if mesh is None:
                 from .dist import make_mesh_1d
                 mesh = make_mesh_1d()
-            meta = (getattr(program, "dist_meta", None) or {})
-            ctx.dist_arrays(mesh, ell=meta.get("needs_ell", False))
-        elif backend != "local":
+            meta = getattr(program, "dist_meta", None)
+            views = ctx.dist_arrays(mesh, **rtd.view_flags(meta))
+        else:
             raise ValueError(
                 f"unknown backend {backend!r}; expected 'local', 'pallas', or "
                 "'distributed'")
+        jax.block_until_ready(views)
     return ctx
+
+
+def on_device(g: CSRGraph) -> CSRGraph:
+    """`g` as a jitted program takes it: a graph built on the host maps to
+    its one device copy (`GraphContext.device_graph`); a graph whose
+    arrays are already jax arrays, or tracers, is returned as it is."""
+    if isinstance(g.indptr, np.ndarray):
+        return get_context(g).device_graph()
+    return g
 
 
 def adopt_patched_views(delta) -> GraphContext:

@@ -29,11 +29,12 @@ def make_mesh_1d(num_devices: int | None = None):
     return make_mesh((n,), (rtd.AXIS,), devices=devs[:n])
 
 
-def prepare(g: CSRGraph, mesh, *, ell: bool = False) -> dict:
+def prepare(g: CSRGraph, mesh, *, ell: bool = False,
+            edge_key: bool = False) -> dict:
     """Partitioned device arrays for `g`, memoized in the graph's shared
     `GraphContext` — repeated runs against one graph partition it once."""
     from .context import get_context
-    return get_context(g).dist_arrays(mesh, ell=ell)
+    return get_context(g).dist_arrays(mesh, ell=ell, edge_key=edge_key)
 
 
 def run(prog, g: CSRGraph, mesh, **params):
@@ -43,7 +44,7 @@ def run(prog, g: CSRGraph, mesh, **params):
     Equivalent to `prog.bind(g, mesh=mesh)(**params)` — prefer `bind` for
     repeated queries against one graph."""
     meta = getattr(prog, "dist_meta", None) or {}
-    gd = prepare(g, mesh, ell=meta.get("needs_ell", False))
+    gd = prepare(g, mesh, **rtd.view_flags(meta))
     return run_prepared(prog, gd, mesh, num_nodes=g.num_nodes, **params)
 
 
@@ -56,7 +57,7 @@ def run_pod_parallel(prog, g: CSRGraph, mesh, source_set, **params):
     centrality contributions are psum'd across pods at the end. Inter-pod
     traffic = one psum of the output — the DCI-friendly schedule."""
     meta = getattr(prog, "dist_meta", None) or {}
-    gd = prepare(g, mesh, ell=meta.get("needs_ell", False))
+    gd = prepare(g, mesh, **rtd.view_flags(meta))
     in_specs = rtd.partition_specs(gd, mesh)          # 'data' only → pod-replicated
     npods = mesh.shape["pod"]
     srcs = np.asarray(source_set, np.int32)
@@ -108,8 +109,8 @@ def _runner(prog, gd: dict, mesh, names: tuple, meta: dict):
     Built once and cached on the program: `jax.jit` keys its own cache on
     function identity, so constructing a fresh lambda per call (the old
     behavior) re-traced and re-compiled on EVERY query — fatal for a query
-    server. `ell_cols` presence is in the key because it changes `gd`'s
-    pytree structure."""
+    server. `gd`'s keys are in the cache key because the views a program
+    reads (`ell_cols`, `edge_key_rep`) change its pytree structure."""
     cache = getattr(prog, "_dist_runner_cache", None)
     if cache is None:
         cache = {}
@@ -117,7 +118,7 @@ def _runner(prog, gd: dict, mesh, names: tuple, meta: dict):
             prog._dist_runner_cache = cache
         except AttributeError:   # e.g. a frozen/slots stand-in program
             pass
-    key = (mesh, names, "ell_cols" in gd)
+    key = (mesh, names, tuple(sorted(gd)))
     fn = cache.get(key)
     if fn is None:
         in_specs = rtd.partition_specs(gd, mesh)

@@ -21,13 +21,16 @@ TPU mesh.
 """
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..graph.csr import CSRGraph
-from ..graph.partition import block_partition_1d
+from ..graph.csr import INF_I32, CSRGraph, _workers
+from ..graph.partition import blocks_1d
+from ..trace import span
 from . import runtime as rt
 
 AXIS = "data"
@@ -37,60 +40,104 @@ AXIS = "data"
 # Graph preparation (host side)
 # --------------------------------------------------------------------------
 
-def prepare_graph_1d(g: CSRGraph, mesh, *, ell: bool = False) -> dict:
+def view_flags(meta) -> dict:
+    """The optional views a distributed program's generated body reads
+    (`dist_meta`), as keyword arguments of `prepare_graph_1d`."""
+    meta = meta or {}
+    return {"ell": bool(meta.get("needs_ell")),
+            "edge_key": bool(meta.get("needs_edge_key"))}
+
+
+def _place_rows(mesh, rows: dict) -> dict:
+    """{key: (shape, row)} -> {key: [P, ...] array sharded over the mesh's
+    'data' axis whose row d is `row(d)`}. Each row is made on the host and
+    put on its own devices only, so no whole array is ever on one device.
+    The rows are made and sent side by side on a pool of threads, each
+    thread holding its row until the device has it."""
+    def put(row, d, device):
+        return jax.device_put(row(d)[None], device).block_until_ready()
+
+    with ThreadPoolExecutor(_workers()) as pool:
+        pending = {}
+        for key, (shape, row) in rows.items():
+            sharding = NamedSharding(mesh, P(AXIS, *([None] * (len(shape) - 1))))
+            where = sharding.addressable_devices_indices_map(shape)
+            pending[key] = (shape, sharding, [
+                pool.submit(put, row, index[0].start or 0, device)
+                for device, index in where.items()])
+        return {key: jax.make_array_from_single_device_arrays(
+                    shape, sharding, [f.result() for f in futures])
+                for key, (shape, sharding, futures) in pending.items()}
+
+
+def prepare_graph_1d(g: CSRGraph, mesh, *, ell: bool = False,
+                     edge_key: bool = False) -> dict:
     """Partitioned arrays for the 1-D backend, placed on `mesh` once.
 
     Keys with leading [P] shard over the mesh 'data' axis; `*_rep` keys are
-    replicated static graph structure (degree tables, the sorted edge key
-    for is_an_edge). Each array is put with its `NamedSharding` here, so a
-    jitted runner never reshards the graph from one device per call."""
+    replicated static graph structure (degree tables; with `edge_key` the
+    sorted edge key for is_an_edge). Device d's out-edges are one slice of
+    the host CSR, and its in-edges one slice of the reverse CSR; each is
+    padded and put on device d's shard alone (span `graph.shard`), so a
+    jitted runner never reshards the graph and no device holds another's
+    edges."""
     p = mesh.shape[AXIS]
-    out = block_partition_1d(g, p)                      # out-edges by src block
-    # in-edges partitioned by dst block: build from the reverse CSR
-    rev = CSRGraph(
-        indptr=g.rev_indptr, indices=g.rev_indices, weights=g.rev_weights,
-        edge_src=g.rev_edge_dst, rev_indptr=g.indptr, rev_indices=g.indices,
-        rev_weights=g.weights, rev_edge_dst=g.edge_src,
-        out_degree=g.in_degree, in_degree=g.out_degree,
-        edge_key=g.rev_edge_dst * jnp.int32(g.num_nodes) + g.rev_indices,
-        num_nodes=g.num_nodes, num_edges=g.num_edges,
-        max_out_degree=g.max_in_degree, max_in_degree=g.max_out_degree)
-    inn = block_partition_1d(rev, p)                    # (dst, src) pairs by dst block
-    block = out.block
-    n_pad = out.num_nodes_padded
-    own_ids = (np.arange(p)[:, None] * block + np.arange(block)[None, :]).astype(np.int32)
+    n = g.num_nodes
+    if edge_key and n * n >= 2**31:
+        raise ValueError(
+            f"the distributed edge key is int32 src*N + dst, which needs "
+            f"N*N < 2**31 (N <= 46,340); this graph has N = {n} "
+            "(ROADMAP.md, Design 2: a row search would lift the limit)")
+    with span("graph.shard"):
+        out = blocks_1d(g.indptr, n, p)                 # out-edges by src block
+        inn = blocks_1d(g.rev_indptr, n, p)             # in-edges by dst block
+        block = out.block
+        n_pad = block * p
+        esrc, edst, ew = (np.asarray(a) for a in (g.edge_src, g.indices, g.weights))
+        # in-edges: `idst` is the OWNED destination, `isrc` the in-neighbor
+        idst, isrc, iw = (np.asarray(a) for a in
+                          (g.rev_edge_dst, g.rev_indices, g.rev_weights))
 
-    deg_out = np.zeros(n_pad, np.int32)
-    deg_out[: g.num_nodes] = np.asarray(g.out_degree)
-    deg_in = np.zeros(n_pad, np.int32)
-    deg_in[: g.num_nodes] = np.asarray(g.in_degree)
+        def local(b, ids, d):
+            # local slot of the owned endpoint; padding edges clipped to 0
+            # and neutralized by the valid mask
+            return np.clip(b.row(ids, d, 0) - d * block, 0, block - 1)
+        rows = {
+            "esrc": (out, lambda d: out.row(esrc, d, 0)),
+            "edst": (out, lambda d: out.row(edst, d, 0)),
+            "ew": (out, lambda d: out.row(ew, d, INF_I32)),
+            "evalid": (out, out.valid),
+            "esrc_local": (out, lambda d: local(out, esrc, d)),
+            "idst": (inn, lambda d: inn.row(idst, d, 0)),
+            "isrc": (inn, lambda d: inn.row(isrc, d, 0)),
+            "iw": (inn, lambda d: inn.row(iw, d, INF_I32)),
+            "ivalid": (inn, inn.valid),
+            "idst_local": (inn, lambda d: local(inn, idst, d)),
+        }
+        rows = {k: ((p, b.emax), row) for k, (b, row) in rows.items()}
+        rows["own_ids"] = ((p, block), lambda d: np.arange(
+            d * block, (d + 1) * block, dtype=np.int32))
+        if ell:
+            from ..graph.csr import to_ell
+            e = to_ell(g)
+            cols = np.asarray(e.cols)
+            cols_pad = np.full((n_pad, e.max_deg), n_pad, np.int32)
+            cols_pad[:n] = np.where(cols == n, n_pad, cols)
+            cols_pad = cols_pad.reshape(p, block, e.max_deg)
+            rows["ell_cols"] = (cols_pad.shape, lambda d: cols_pad[d])
+        gd = _place_rows(mesh, rows)
 
-    gd = {
-        "esrc": out.src, "edst": out.dst, "ew": out.weight, "evalid": out.valid,
-        # local slot of the source vertex; padding edges clipped to 0 and
-        # neutralized by the valid mask
-        "esrc_local": np.clip(
-            out.src - (np.arange(p) * block)[:, None], 0, block - 1).astype(np.int32),
-        # in-edge arrays: src field of `inn` is the OWNED dst, dst field is the in-neighbor
-        "idst": inn.src, "isrc": inn.dst, "iw": inn.weight, "ivalid": inn.valid,
-        "idst_local": np.clip(
-            inn.src - (np.arange(p) * block)[:, None], 0, block - 1).astype(np.int32),
-        "own_ids": own_ids,
-        "out_degree_rep": deg_out,
-        "in_degree_rep": deg_in,
-        "n_true_rep": np.asarray(g.num_nodes, np.int32),
-        "edge_key_rep": np.asarray(g.edge_key),   # cached, built once in from_edges
-    }
-    if ell:
-        from ..graph.csr import to_ell
-        e = to_ell(g)
-        cols = np.asarray(e.cols)
-        cols_pad = np.full((n_pad, e.max_deg), n_pad, np.int32)
-        cols_pad[: g.num_nodes] = np.where(cols == g.num_nodes, n_pad, cols)
-        gd["ell_cols"] = cols_pad.reshape(p, block, e.max_deg)
-    specs = partition_specs(gd, mesh)
-    return {k: jax.device_put(v, NamedSharding(mesh, specs[k]))
-            for k, v in gd.items()}
+        deg_out = np.zeros(n_pad, np.int32)
+        deg_out[:n] = np.asarray(g.out_degree)
+        deg_in = np.zeros(n_pad, np.int32)
+        deg_in[:n] = np.asarray(g.in_degree)
+        rep = {"out_degree_rep": deg_out, "in_degree_rep": deg_in,
+               "n_true_rep": np.asarray(n, np.int32)}
+        if edge_key:
+            rep["edge_key_rep"] = (esrc.astype(np.int64) * n + edst).astype(np.int32)
+        replicated = NamedSharding(mesh, P())
+        gd.update({k: jax.device_put(v, replicated) for k, v in rep.items()})
+    return gd
 
 
 def partition_specs(gd: dict, mesh):

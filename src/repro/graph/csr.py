@@ -10,7 +10,9 @@ so edge-parallel ops are a gather, not a searchsorted.
 from __future__ import annotations
 
 import dataclasses
+import os
 import warnings
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 import jax
@@ -163,14 +165,168 @@ class CSRGraph:
         return jnp.max(self.weights)
 
 
-def _build_csr(n: int, src: np.ndarray, dst: np.ndarray, w: np.ndarray):
-    with span("graph.csr"):
-        order = np.lexsort((dst, src))
-        src, dst, w = src[order], dst[order], w[order]
-        indptr = np.zeros(n + 1, np.int64)
-        np.add.at(indptr, src + 1, 1)
-        indptr = np.cumsum(indptr)
-        return indptr.astype(np.int32), dst.astype(np.int32), w.astype(np.int32), src.astype(np.int32)
+def _workers() -> int:
+    """Host threads for the graph build: the CPUs this process may use, at
+    most 16 (the build is bound by memory bandwidth well before that)."""
+    if hasattr(os, "sched_getaffinity"):
+        return min(len(os.sched_getaffinity(0)), 16)
+    return min(os.cpu_count() or 1, 16)
+
+
+# below this many edges the host build runs as one piece
+_PARALLEL_MIN_EDGES = 1 << 20
+# edges per task: the temporaries in flight are a few of these per thread,
+# whatever the graph's size
+_PIECE = 1 << 22
+
+
+def _pieces(m: int, k: int) -> list:
+    """`k` contiguous (start, stop) ranges covering [0, m)."""
+    cut = np.linspace(0, m, k + 1).astype(np.int64)
+    return list(zip(cut[:-1].tolist(), cut[1:].tolist()))
+
+
+def _tasks(m: int) -> list:
+    """[0, m) in ranges of at most `_PIECE` edges."""
+    return _pieces(m, max(1, -(-m // _PIECE)))
+
+
+def _sort_edges(n: int, chunks: list, dedup: bool, pool, workers: int):
+    """Edges sorted by (major, minor), ties in input order; with `dedup`
+    only the first edge of each run of equal (major, minor) pairs stays.
+
+    `chunks` is the edge list as consecutive pieces of (major, minor,
+    weight) int32 arrays; the list is emptied once they are scattered, so
+    a caller that holds no other reference frees them. A bucket of 2**shift consecutive major ids holds
+    the high bits of an edge's major id; one int64 key holds the rest of
+    it, the minor id and the edge's position in the bucket, which comes
+    last, so sorting the keys alone is a stable sort. Edges are scattered
+    stably into their buckets, each bucket's keys are sorted, and the keys
+    are unpacked. Every step runs over pieces on `pool`'s threads (NumPy's
+    sorts, scatters and gathers release the GIL).
+
+    Returns (major, minor, weight) int32 arrays and each major id's count.
+    No step holds an array of all N ids per piece (`np.bincount` of the
+    major ids would, a copy per piece), so the memory is O(E) + O(N)."""
+    nbits = max(n - 1, 0).bit_length()
+    # the major ids' histogram in 2**fine-id bins sizes the buckets
+    fine = max(nbits - 16, 0)
+    nfine = ((n - 1) >> fine) + 1 if n else 0
+    hist = np.zeros(nfine, np.int64)
+    for h in pool.map(lambda ch: np.bincount((ch[0] >> fine).astype(np.intp),
+                                             minlength=nfine), chunks):
+        hist += h
+    m = int(hist.sum())
+    if m == 0:
+        empty = np.zeros(0, np.int32)
+        return empty, empty, empty, np.zeros(n, np.int64)
+    # as few buckets as keep the threads busy, as many as the key needs
+    want = 4 * workers if m >= _PARALLEL_MIN_EDGES else 1
+    shift = max(nbits - (want - 1).bit_length(), fine)
+    while True:
+        sizes = np.add.reduceat(hist, np.arange(0, nfine, 1 << (shift - fine)))
+        pbits = (int(sizes.max()) - 1).bit_length()
+        if shift + nbits + pbits <= 63 or shift == fine:
+            break
+        shift -= 1
+    if shift + nbits + pbits > 63:
+        raise ValueError(f"{m} edges over {n} vertices do not fit the "
+                         "63-bit sort key")
+    nb = len(sizes)
+    bstart = np.zeros(nb + 1, np.int64)
+    np.cumsum(sizes, out=bstart[1:])
+    bdt = np.uint8 if nb <= 1 << 8 else np.uint16 if nb <= 1 << 16 else np.uint32
+
+    def bucket(ch):
+        b = (ch[0] >> shift).astype(bdt)
+        return b, np.bincount(b.astype(np.intp), minlength=nb)
+    parts = list(pool.map(bucket, chunks))
+    per = np.array([c for _, c in parts], np.int64).reshape(len(chunks), nb)
+    # where each chunk's run of each bucket starts, chunks in order
+    off = bstart[:-1] + np.cumsum(per, axis=0) - per
+    keys = np.empty(m, np.int64)
+    wts = np.empty(m, np.int32)
+
+    def scatter(k):
+        (maj, mnr, w), (b, c) = chunks[k], parts[k]
+        order = np.argsort(b, kind="stable")
+        bs = b[order]
+        dest = off[k][bs] + (np.arange(len(bs)) - (np.cumsum(c) - c)[bs])
+        keys[dest] = (((maj[order] & ((1 << shift) - 1)).astype(np.int64)
+                       << (nbits + pbits))
+                      | (mnr[order].astype(np.int64) << pbits)
+                      | (dest - bstart[bs]))
+        wts[dest] = w[order]
+    list(pool.map(scatter, range(len(chunks))))
+    chunks.clear()
+    del parts
+
+    # buckets in groups of about m / (4 workers) edges, one task a group
+    firsts = [a for a, _ in _pieces(m, 4 * workers)]
+    cuts = np.unique(np.concatenate(
+        ([0], np.searchsorted(bstart, firsts, side="right") - 1, [nb])))
+
+    def sort(i):
+        for b in range(cuts[i], cuts[i + 1]):
+            keys[bstart[b]:bstart[b + 1]].sort()
+    list(pool.map(sort, range(len(cuts) - 1)))
+
+    pmask, nmask = (1 << pbits) - 1, (1 << nbits) - 1
+    # without dedup every edge stays where it lies: write the results in place
+    direct = None if dedup else [np.empty(m, np.int32) for _ in range(3)]
+
+    def unpack(piece):
+        a, z = piece
+        lo = max(a - 1, 0)         # the edge before, to compare against
+        b0 = int(np.searchsorted(bstart, lo, side="right")) - 1
+        b1 = int(np.searchsorted(bstart, z - 1, side="right")) - 1
+        bids = np.arange(b0, b1 + 1)
+        bk = np.repeat(bids, np.minimum(bstart[bids + 1], z)
+                       - np.maximum(bstart[bids], lo))
+        k = keys[lo:z]
+        pair = k >> pbits
+        maj = ((bk << shift) + (pair >> nbits)).astype(np.int32)
+        mnr = (pair & nmask).astype(np.int32)
+        w = wts[bstart[bk] + (k & pmask)]
+        if dedup:
+            keep = np.ones(len(k), bool)
+            full = (maj.astype(np.int64) << nbits) | mnr
+            np.not_equal(full[1:], full[:-1], out=keep[1:])
+            keep[0] = lo == a
+            maj, mnr, w = maj[keep], mnr[keep], w[keep]
+        elif lo < a:
+            maj, mnr, w = maj[1:], mnr[1:], w[1:]
+        # the piece's ids are sorted: count them over their own range
+        first = int(maj[0]) if len(maj) else 0
+        c = np.bincount((maj - first).astype(np.intp))
+        if direct is not None:
+            for res, got in zip(direct, (maj, mnr, w)):
+                res[a:z] = got
+            return [None, None, None, first, c]
+        return [maj, mnr, w, first, c]
+    out = list(pool.map(unpack, _tasks(m)))
+    counts = np.zeros(n, np.int64)
+    for _, _, _, first, c in out:
+        counts[first:first + len(c)] += c
+    if direct is not None:
+        return (*direct, counts)
+
+    def assemble(i):
+        # one piece at a time, each freed once copied
+        res = np.empty(sum(len(o[i]) for o in out), np.int32)
+        pos = 0
+        for o in out:
+            res[pos:pos + len(o[i])] = o[i]
+            pos += len(o[i])
+            o[i] = None
+        return res
+    return assemble(0), assemble(1), assemble(2), counts
+
+
+def _row_pointers(counts: np.ndarray) -> np.ndarray:
+    indptr = np.zeros(len(counts) + 1, np.int64)
+    np.cumsum(counts, out=indptr[1:])
+    return indptr.astype(np.int32)
 
 
 def from_edges(
@@ -183,46 +339,72 @@ def from_edges(
     dedup: bool = True,
     drop_self_loops: bool = False,
 ) -> CSRGraph:
-    """Build a CSRGraph (host-side numpy; the result is a device pytree)."""
-    src = np.asarray(src, np.int64)
-    dst = np.asarray(dst, np.int64)
-    if weights is None:
-        w = np.ones_like(src)
-    else:
-        w = np.asarray(weights, np.int64)
-    if undirected:
-        src, dst, w = np.concatenate([src, dst]), np.concatenate([dst, src]), np.concatenate([w, w])
-    if drop_self_loops:
-        keep = src != dst
-        src, dst, w = src[keep], dst[keep], w[keep]
-    if dedup and len(src):
-        with span("graph.dedup"):
-            key = src * np.int64(n) + dst
-            _, first = np.unique(key, return_index=True)
-            src, dst, w = src[first], dst[first], w[first]
-    e = len(src)
-    indptr, indices, w_s, edge_src = _build_csr(n, src, dst, w)
-    rev_indptr, rev_indices, rev_w, rev_edge_dst = _build_csr(n, dst, src, w)
-    out_deg = np.diff(indptr).astype(np.int32)
-    in_deg = np.diff(rev_indptr).astype(np.int32)
-    # CSR order is lexsorted by (src, dst), so the key array is sorted by
-    # construction; int64 intermediate avoids silent wrap while building.
-    edge_key = (edge_src.astype(np.int64) * n + indices.astype(np.int64)).astype(np.int32)
-    with span("graph.to_device"):
-        arrays = dict(
-            indptr=jnp.asarray(indptr),
-            indices=jnp.asarray(indices),
-            weights=jnp.asarray(w_s),
-            edge_src=jnp.asarray(edge_src),
-            rev_indptr=jnp.asarray(rev_indptr),
-            rev_indices=jnp.asarray(rev_indices),
-            rev_weights=jnp.asarray(rev_w),
-            rev_edge_dst=jnp.asarray(rev_edge_dst),
-            out_degree=jnp.asarray(out_deg),
-            in_degree=jnp.asarray(in_deg),
-            edge_key=jnp.asarray(edge_key))
+    """Build a CSRGraph on the host: every array is numpy. Its device
+    copies are views its `GraphContext` builds (`device_graph()` on one
+    device, `dist_arrays(mesh)` partitioned), so no array of the whole
+    graph goes to a device that does not run the whole graph.
+
+    With `undirected` each edge is also taken in reverse (the reversed
+    copies after all the given ones), with `drop_self_loops` self-loops
+    go, and with `dedup` the first copy of each repeated edge stays."""
+    src = np.asarray(src)
+    dst = np.asarray(dst)
+    if src.shape != dst.shape or src.ndim != 1:
+        raise ValueError(f"src and dst must be 1-D of one length, got "
+                         f"{src.shape} and {dst.shape}")
+    for name, ids in (("src", src), ("dst", dst)):
+        if len(ids) and (int(ids.min()) < 0 or int(ids.max()) >= n):
+            raise ValueError(f"{name} holds vertex ids outside [0, {n})")
+    src = src.astype(np.int32, copy=False)
+    dst = dst.astype(np.int32, copy=False)
+    w = np.ones(len(src), np.int32) if weights is None else np.asarray(weights)
+    if w.dtype != np.int32:      # int64 first: floats truncate, as ever
+        w = w.astype(np.int64).astype(np.int32)
+    workers = _workers() if len(src) >= _PARALLEL_MIN_EDGES else 1
+
+    def piece(args):
+        (a, z), flip = args
+        s, d, ww = src[a:z], dst[a:z], w[a:z]
+        if flip:
+            s, d = d, s
+        if drop_self_loops:
+            keep = s != d
+            s, d, ww = s[keep], d[keep], ww[keep]
+        return s, d, ww
+
+    with ThreadPoolExecutor(workers) as pool:
+        spans = _tasks(len(src))
+        chunks = list(pool.map(piece, [(p, False) for p in spans]
+                               + [(p, True) for p in spans if undirected]))
+        # the forward CSR's one sort, which also drops the duplicates
+        with span("graph.dedup" if dedup else "graph.csr"):
+            edge_src, indices, w_s, out_deg = _sort_edges(n, chunks, dedup,
+                                                          pool, workers)
+        e = len(indices)
+        with span("graph.csr"):
+            indptr = _row_pointers(out_deg)
+        with span("graph.csr"):
+            rchunks = [(indices[a:z], edge_src[a:z], w_s[a:z])
+                       for a, z in _tasks(e)]
+            rev_edge_dst, rev_indices, rev_w, in_deg = _sort_edges(
+                n, rchunks, False, pool, workers)
+            rev_indptr = _row_pointers(in_deg)
+            # CSR order is sorted by (src, dst), so the key array is sorted
+            # by construction; int64 intermediate avoids silent wrap while
+            # building
+            edge_key = np.empty(e, np.int32)
+
+            def key(p):
+                a, z = p
+                edge_key[a:z] = edge_src[a:z].astype(np.int64) * n + indices[a:z]
+            list(pool.map(key, _tasks(e)))
+    out_deg = out_deg.astype(np.int32)
+    in_deg = in_deg.astype(np.int32)
     return CSRGraph(
-        **arrays,
+        indptr=indptr, indices=indices, weights=w_s,
+        edge_src=edge_src, rev_indptr=rev_indptr,
+        rev_indices=rev_indices, rev_weights=rev_w, rev_edge_dst=rev_edge_dst,
+        out_degree=out_deg, in_degree=in_deg, edge_key=edge_key,
         num_nodes=int(n),
         num_edges=int(e),
         max_out_degree=int(out_deg.max(initial=1)),
@@ -379,23 +561,26 @@ def to_sliced_ell(
 
 def pad_nodes(g: CSRGraph, multiple: int) -> CSRGraph:
     """Pad to a node-count multiple (the paper pads the last MPI shard; we pad
-    so every device shard has identical extent)."""
+    so every device shard has identical extent). A host view, like `g`'s
+    own arrays from `from_edges`."""
     n = g.num_nodes
     n_pad = _round_up(max(n, 1), multiple)
     if n_pad == n:
         return g
     extra = n_pad - n
-    def pad_ptr(p):
-        p = np.asarray(p)
-        return jnp.asarray(np.concatenate([p, np.full(extra, p[-1], p.dtype)]))
+
+    def pad(a, fill):
+        a = np.asarray(a)
+        return np.concatenate([a, np.full(extra, fill, a.dtype)])
+    edge_src, indices = np.asarray(g.edge_src), np.asarray(g.indices)
     return dataclasses.replace(
         g,
-        indptr=pad_ptr(g.indptr),
-        rev_indptr=pad_ptr(g.rev_indptr),
-        out_degree=jnp.concatenate([g.out_degree, jnp.zeros(extra, jnp.int32)]),
-        in_degree=jnp.concatenate([g.in_degree, jnp.zeros(extra, jnp.int32)]),
+        indptr=pad(g.indptr, np.asarray(g.indptr)[-1]),
+        rev_indptr=pad(g.rev_indptr, np.asarray(g.rev_indptr)[-1]),
+        out_degree=pad(g.out_degree, 0),
+        in_degree=pad(g.in_degree, 0),
         # the key encodes num_nodes, so it must be rebuilt for the new N
         # (still sorted: CSR order is (src, dst)-lexicographic)
-        edge_key=g.edge_src * jnp.int32(n_pad) + g.indices,
+        edge_key=(edge_src.astype(np.int64) * n_pad + indices).astype(np.int32),
         num_nodes=n_pad,
     )

@@ -2,12 +2,14 @@
 
 Two schemes:
 
-1. `block_partition_1d` — the paper's MPI scheme (§3.1/§4.2): contiguous
+1. `blocks_1d` — the paper's MPI scheme (§3.1/§4.2): contiguous
    equal-size vertex blocks per device ("index-based partitioning"), with the
    last block padded ("we pad temporary vertices for the last process").
-   Every device owns the out-edges of its vertex block. Per-device edge
-   counts differ, so each device's edge array is padded to the global max
-   with harmless sentinel edges (src=dst=0, weight=INF, valid=0).
+   Every device owns the out-edges of its vertex block, which in a CSR are
+   one contiguous slice. Per-device edge counts differ, so each device's
+   edge array is padded to the global max with harmless sentinel edges
+   (src=dst=0, weight=INF, valid=0); `runtime_dist.prepare_graph_1d` puts
+   each device's slices on that device alone.
 
 2. `partition_2d` — beyond-paper CombBLAS-style 2-D partitioning for the
    (data × model) mesh. The adjacency is blocked into R×C tiles; device
@@ -19,8 +21,8 @@ Two schemes:
      own' = reduce_scatter(part, axis='model', combiner)# N/(R·C)
    i.e. O(N/C + N/R) collective bytes/device/step instead of the 1-D O(N).
 
-Both produce host-side numpy arrays stacked on leading device axes so they
-can be dropped straight into `shard_map` via NamedSharding.
+`partition_2d` produces host-side numpy arrays stacked on leading device
+axes so they can be dropped straight into `shard_map` via NamedSharding.
 """
 from __future__ import annotations
 
@@ -36,38 +38,36 @@ def _ceil_div(a: int, b: int) -> int:
 
 
 @dataclasses.dataclass(frozen=True)
-class Partition1D:
-    """Edges partitioned by source-vertex block; stacked [P, Emax]."""
-    src: np.ndarray      # int32[P, Emax]  global src id
-    dst: np.ndarray      # int32[P, Emax]  global dst id
-    weight: np.ndarray   # int32[P, Emax]
-    valid: np.ndarray    # bool [P, Emax]
-    num_devices: int
+class Blocks1D:
+    """Where each device's edges lie in a CSR: device d owns vertices
+    [d*block, (d+1)*block), and the CSR being sorted by the blocked vertex,
+    its edges are the one slice bounds[d]:bounds[d+1]."""
     block: int           # vertices per device (padded)
-    num_nodes_padded: int
+    bounds: np.ndarray   # int64[P+1] edge offsets
+    emax: int            # the longest device's edge count (at least 1)
+
+    def row(self, arr, d: int, fill) -> np.ndarray:
+        """Device d's slice of the E-sized `arr`, padded with `fill` to
+        Emax: int32[Emax]."""
+        lo, hi = self.bounds[d], self.bounds[d + 1]
+        out = np.empty(self.emax, np.int32)
+        out[:hi - lo] = arr[lo:hi]
+        out[hi - lo:] = fill
+        return out
+
+    def valid(self, d: int) -> np.ndarray:
+        """bool[Emax]: which of device d's slots hold an edge."""
+        return np.arange(self.emax) < self.bounds[d + 1] - self.bounds[d]
 
 
-def block_partition_1d(g: CSRGraph, num_devices: int) -> Partition1D:
-    p = num_devices
-    block = _ceil_div(g.num_nodes, p)
-    n_pad = block * p
-    src = np.asarray(g.edge_src)
-    dst = np.asarray(g.indices)
-    w = np.asarray(g.weights)
-    owner = src // block
-    emax = max(int(np.bincount(owner, minlength=p).max()) if len(src) else 0, 1)
-    out_src = np.zeros((p, emax), np.int32)
-    out_dst = np.zeros((p, emax), np.int32)
-    out_w = np.full((p, emax), int(INF_I32), np.int32)
-    out_valid = np.zeros((p, emax), bool)
-    for d in range(p):
-        sel = owner == d
-        k = int(sel.sum())
-        out_src[d, :k] = src[sel]
-        out_dst[d, :k] = dst[sel]
-        out_w[d, :k] = w[sel]
-        out_valid[d, :k] = True
-    return Partition1D(out_src, out_dst, out_w, out_valid, p, block, n_pad)
+def blocks_1d(indptr, num_nodes: int, num_devices: int) -> Blocks1D:
+    """The 1-D block layout of a CSR with row pointers `indptr` over
+    `num_devices` devices, the last block padded."""
+    block = _ceil_div(num_nodes, num_devices)
+    first = np.minimum(np.arange(num_devices + 1) * block, num_nodes)
+    bounds = np.asarray(indptr, np.int64)[first]
+    emax = max(int(np.diff(bounds).max(initial=0)), 1)
+    return Blocks1D(block, bounds, emax)
 
 
 @dataclasses.dataclass(frozen=True)

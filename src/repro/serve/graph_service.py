@@ -72,7 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..autotune import TuningStore, source_digest
-from ..core import compile_bundled, load_program_source, prepare
+from ..core import compile_bundled, load_program_source, on_device, prepare
 from ..core import runtime as rt
 from ..core.analysis import ERROR as ANALYSIS_ERROR
 from ..core.analysis import check_schedule, program_analysis
@@ -218,7 +218,7 @@ class SsspKind(QueryKind):
             arr = np.full(b, srcs[0], np.int32)
             arr[:len(srcs)] = srcs
             dist = jax.block_until_ready(
-                batched(handle.graph, jnp.asarray(arr)))
+                batched(on_device(handle.graph), jnp.asarray(arr)))
             dist = np.asarray(dist)
             return [dist[i] for i in range(len(srcs))]
 
@@ -240,7 +240,7 @@ class BfsKind(QueryKind):
             b = _pad_width(len(srcs), width)
             arr = np.full(b, srcs[0], np.int32)
             arr[:len(srcs)] = srcs
-            level, _depth = batched(handle.graph, jnp.asarray(arr))
+            level, _depth = batched(on_device(handle.graph), jnp.asarray(arr))
             level = np.asarray(jax.block_until_ready(level))
             return [level[i] for i in range(len(srcs))]
 
@@ -292,7 +292,7 @@ class PprKind(QueryKind):
             arr = np.full(b, srcs[0], np.int32)
             arr[:len(srcs)] = srcs
             rank = jax.block_until_ready(
-                batched(handle.graph, jnp.asarray(arr)))
+                batched(on_device(handle.graph), jnp.asarray(arr)))
             rank = np.asarray(rank)
             return [rank[i] for i in range(len(srcs))]
 

@@ -25,7 +25,8 @@ is nested inside it, so it is not added again).
 Device counters. A generated program's result key that starts with `_` is
 a device counter, not an output: the local and pallas backends return
 `_supersteps`, `_push_steps`, `_edges_active` and `_edges_swept` from
-every top-level loop, the distributed backend `_gather_elems`.
+every top-level loop, the distributed backend `_supersteps` and
+`_gather_elems`.
 `counters(result)` fetches them to the host in one transfer, after the
 run. `BoundProgram.__call__` keeps a reference to each call's counters
 (device scalars, not fetched) on its `run` record, so a caller can read

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import compile_bundled, runtime as rt
+from repro.core import compile_bundled, on_device, runtime as rt
 from repro.graph import from_edges, preferential_attachment, uniform_random
 from repro.graph.csr import INF_I32
 from repro.kernels.ell_spmv import ops as kops
@@ -21,7 +21,8 @@ from repro.kernels.ell_spmv.kernel import ell_spmv
 
 @pytest.fixture(scope="module")
 def g_pl():
-    return preferential_attachment(500, m=5, seed=7)
+    # the runtime primitives below run eagerly on the graph's device copy
+    return on_device(preferential_attachment(500, m=5, seed=7))
 
 
 # --- batched runtime primitives ---------------------------------------------
@@ -175,7 +176,8 @@ def test_is_an_edge_and_tc_beyond_46k_nodes():
     chord_i = np.array([10, 1000, 20_000, 30_000, 46_000], np.int64)
     src = np.concatenate([ring_src, chord_i])
     dst = np.concatenate([ring_dst, chord_i + 2])
-    g = from_edges(n, src, dst, np.ones(len(src), np.int64), undirected=True)
+    g = on_device(from_edges(n, src, dst, np.ones(len(src), np.int64),
+                             undirected=True))
     assert not rt._edge_key_fits_i32(g.num_nodes)
     hits = np.asarray(rt.is_an_edge(
         g, jnp.asarray(np.array([10, 10, 46_000, 5], np.int32)),

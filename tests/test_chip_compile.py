@@ -5,8 +5,9 @@ described, not attached. These tests compile the per-iteration kernels of
 the local backend, and its whole generated `sssp` and `pr` programs, at
 the shapes of an RMAT scale-21 graph (the size
 `chip_smoke.py` runs) and check they fit one v5e's 16 GB and that each
-neighbor loop gathers over the edges once, and they pin what Mosaic
-answers for the Pallas ELL kernel. Nothing runs, so they say
+neighbor loop gathers over the edges once; they compile the distributed
+`pr` at the four-chip benchmark cell's shapes for a v5e 2x2; and they pin
+what Mosaic answers for the Pallas ELL kernel. Nothing runs, so they say
 nothing about results or times.
 
 The topology is described inside a module fixture, never at import: only
@@ -37,18 +38,23 @@ ELL_WIDTH, ELL_ROWS, ELL_BLOCK = 128, 4096, 256
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     mp = pytest.MonkeyPatch()
     mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         mp.undo()
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     mp.undo()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _shape(sharding, shape, dtype=jnp.int32):
@@ -171,6 +177,45 @@ def test_one_edge_gather_per_sweep(one_chip):
     branches = _callee(sssp[body], "conditional", "branch_computations")
     assert len(branches) == 2
     assert [_edge_gathers(sssp, b) for b in branches] == [1, 1]
+
+
+# the four-chip cell: Graph500 scale 24 split 1-D over a v5e 2x2, each
+# chip's out- and in-edges padded to the longest chip's (about E/4 of the
+# 0.5G directed edges, with room for the blocks' imbalance)
+DIST_N = 2 ** 24
+DIST_EMAX = 132_000_000
+
+
+def test_distributed_pr_at_scale_24_fits_four_chips(topo):
+    """The distributed `pr` program at the four-chip cell's shapes: every
+    chip's shards (which it holds whether the program reads them or not)
+    and the program's temporaries fit one v5e, and the ranks cross the
+    chips by all-gather."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core import dist, runtime_dist as rtd
+    mesh = dist.make_mesh((4,), (rtd.AXIS,), devices=topo.devices)
+    rows = NamedSharding(mesh, P(rtd.AXIS, None))
+    block = DIST_N // 4
+    gd = {k: jax.ShapeDtypeStruct((4, DIST_EMAX), jnp.int32, sharding=rows)
+          for k in ("esrc", "edst", "ew", "esrc_local", "idst", "isrc", "iw",
+                    "idst_local")}
+    gd.update({k: jax.ShapeDtypeStruct((4, DIST_EMAX), jnp.bool_, sharding=rows)
+               for k in ("evalid", "ivalid")})
+    gd["own_ids"] = jax.ShapeDtypeStruct((4, block), jnp.int32, sharding=rows)
+    rep = NamedSharding(mesh, P())
+    gd.update({k: jax.ShapeDtypeStruct((DIST_N,), jnp.int32, sharding=rep)
+               for k in ("out_degree_rep", "in_degree_rep")})
+    gd["n_true_rep"] = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
+    prog = compile_bundled("pr", backend="distributed")
+    names = ("beta", "delta", "maxIter")
+    runner = dist._runner(prog, gd, mesh, names, prog.dist_meta)
+    compiled = runner.lower(gd, 1e-4, 0.85, 100).compile()
+    shards = sum(a.size * a.dtype.itemsize // 4 for k, a in gd.items()
+                 if not k.endswith("_rep"))
+    m = compiled.memory_analysis()
+    assert shards + m.temp_size_in_bytes + m.output_size_in_bytes < HBM_BYTES
+    assert "all-gather" in compiled.as_text()
 
 
 @pytest.mark.parametrize("lanes,error", [(None, MOSAIC_ERRORS[0]),

@@ -9,7 +9,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core import Schedule, compile_bundled
 from repro.graph import from_edges
 from repro.graph.csr import INF_I32, to_ell
-from repro.graph.partition import block_partition_1d, partition_2d
+from repro.graph.partition import blocks_1d, partition_2d
 
 
 def graphs(max_n=24, max_e=80):
@@ -42,8 +42,9 @@ def test_csr_roundtrip(g):
 @given(graphs())
 def test_partition_covers_all_edges(g):
     for p in (2, 3, 4):
-        part = block_partition_1d(g, p)
-        assert int(part.valid.sum()) == g.num_edges
+        for indptr in (g.indptr, g.rev_indptr):
+            b = blocks_1d(indptr, g.num_nodes, p)
+            assert sum(int(b.valid(d).sum()) for d in range(p)) == g.num_edges
     part2 = partition_2d(g, 2, 2)
     assert int(part2.valid.sum()) == g.num_edges
 

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from repro import trace
-from repro.core import Schedule, compile_bundled
+from repro.core import Schedule, compile_bundled, prepare
 from repro.graph import powerlaw_social, rmat, road
 
 SCHEDULES = {
@@ -113,10 +113,15 @@ def test_span_agrees_with_the_profiler_trace_within_1ms(tmp_path):
 def test_graph_build_and_compile_phases_are_spans():
     trace.reset()
     g = rmat(6, seed=1)
-    compile_bundled("sssp", schedule=Schedule(push_threshold_frac=0.03125))
+    prog = compile_bundled("sssp", schedule=Schedule(push_threshold_frac=0.03125))
     compile_bundled("sssp", schedule=Schedule(push_threshold_frac=0.03125))   # a hit
     names = [r["name"] for r in trace.records()]
     assert names.count("graph.dedup") == 1 and names.count("graph.csr") == 2
+    # the host build puts nothing on a device; `prepare` copies it there once
+    assert names.count("graph.to_device") == 0
+    prepare(g, program=prog)
+    prog.bind(g)
+    names = [r["name"] for r in trace.records()]
     assert names.count("graph.to_device") == 1
     assert names.count("compile.parse") == 1 and names.count("compile.codegen") == 1
     assert g.num_nodes == 64
@@ -187,15 +192,17 @@ def test_auto_direction_both_pushes_and_pulls():
 
 
 def test_distributed_returns_only_gather_elems(eight_devices, g_grid):
-    """The distributed backend carries no superstep counters: its one
-    device counter stays `_gather_elems`."""
+    """Of the superstep counters the distributed backend carries only
+    `_supersteps`, beside its exchange counter `_gather_elems`: a shard
+    sweeps only its own edges, so the relax counters have no twin there."""
     from repro.core.dist import make_mesh_1d
     prog = compile_bundled("sssp", backend="distributed")
     out = prog.bind(g_grid, mesh=make_mesh_1d(4))(src=0)
-    assert set(trace.device_counters(out)) == {"_gather_elems"}
-    assert "_supersteps" not in prog.source
+    assert set(trace.device_counters(out)) == {"_gather_elems", "_supersteps"}
+    assert "_push_steps" not in prog.source
     local = compile_bundled("sssp")(g_grid, src=0)
     assert np.array_equal(np.asarray(out["dist"]), np.asarray(local["dist"]))
+    assert int(out["_supersteps"]) == int(local["_supersteps"])
 
 
 def test_outputs_strip_only_the_counters(g_grid):
